@@ -14,6 +14,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     choices=[None, "kernels", "convergence", "roofline"])
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     if args.only in (None, "kernels"):

@@ -42,7 +42,6 @@ from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple, \
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import backend as nbackend
@@ -363,8 +362,8 @@ def compressed_allreduce_1d(g: jnp.ndarray, mesh: Mesh, axis: str = "data",
     n = mesh.shape[axis]
     body = functools.partial(compressed_allreduce_axis, axis_name=axis,
                              axis_size=n, backend=backend)
-    return shard_map(body, mesh=mesh,
-                     in_specs=P(), out_specs=P(), check_rep=False)(g)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=P(), out_specs=P(), check_vma=False)(g)
 
 
 def compressed_grad_sync(grads, mesh: Mesh, axis: str = "data",
@@ -386,13 +385,14 @@ def compressed_grad_sync(grads, mesh: Mesh, axis: str = "data",
                 # f32 round-trip would truncate and drop bits past 2^24
                 def plain_int(x):
                     return jax.lax.psum(x, axis) // n
-                return shard_map(plain_int, mesh=mesh, in_specs=P(),
-                                 out_specs=P(), check_rep=False)(g)
+                return jax.shard_map(plain_int, mesh=mesh, in_specs=P(),
+                                     out_specs=P(), check_vma=False)(g)
 
             def plain(x):
                 return jax.lax.psum(x, axis) / n
-            return shard_map(plain, mesh=mesh, in_specs=P(), out_specs=P(),
-                             check_rep=False)(g.astype(jnp.float32)).astype(g.dtype)
+            return jax.shard_map(plain, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)(
+                g.astype(jnp.float32)).astype(g.dtype)
         flat = g.reshape(-1).astype(jnp.float32)
         out = compressed_allreduce_1d(flat, mesh, axis, backend) / n
         return out.reshape(g.shape).astype(g.dtype)

@@ -436,7 +436,8 @@ def _payload_flash_bwd(be, qq, qk, qv, qg, lse, delta, causal, window,
             qg.payload.reshape(b * kvh * g, sq, d),
             (qq.alpha, qq.beta), (qk.alpha, qk.beta), (qv.alpha, qv.beta),
             (qg.alpha, qg.beta),
-            lse.reshape(b * kvh * g, sq), delta.reshape(b * kvh * g, sq),
+            lse.reshape(b * kvh * g, sq, 1),
+            delta.reshape(b * kvh * g, sq, 1),
             g=g, causal=causal, window=window, scale=scale, bq=bq, bk=bk)
         dq = dq.reshape(b, kvh, g, sq, d)
         # the kernel emits per-head dk/dv (each output block written once);

@@ -644,10 +644,7 @@ REDUCE_PRIMS = ("reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
                 "argmax", "argmin")
 
 
-try:                                    # jax >= 0.4.33; jax.core alias
-    from jax.extend.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
-except ImportError:                     # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
+from jax.extend.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
 
 
 def _extract_jaxprs(v):

@@ -18,6 +18,8 @@ divergence from S2FP8's convergence, reproducing the paper's mechanism):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,35 +29,54 @@ def _key(seed: int, step: int, salt: int = 0):
     return jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(seed), step), salt)
 
 
-def make_markov_table(seed: int, vocab: int, branching: int = 4) -> jnp.ndarray:
-    """Each token has `branching` likely successors; returns [V, V] logits."""
+_MARKOV_FLOOR = -4.0         # logit of every non-successor token
+
+
+def make_markov_table(seed: int, vocab: int, branching: int = 4):
+    """Each token has `branching` likely successors.
+
+    Returns the chain's sparse form ``(succ [V, branching] int32, logit
+    [V, branching] f32)``: row ``v`` of the dense ``[V, V]`` logit table is
+    ``_MARKOV_FLOOR`` except ``logit[v]`` at columns ``succ[v]``.  The dense
+    table would be V^2 floats (275 GB at a 262144 vocab); :func:`lm_batch`
+    rebuilds only the rows it samples from.
+    """
     rng = np.random.default_rng(seed)
-    table = np.full((vocab, vocab), -4.0, np.float32)
+    succ = np.empty((vocab, branching), np.int32)
+    logit = np.empty((vocab, branching), np.float32)
     for v in range(vocab):
-        nxt = rng.choice(vocab, size=branching, replace=False)
-        table[v, nxt] = rng.normal(2.0, 0.5, branching)
-    return jnp.asarray(table)
+        succ[v] = rng.choice(vocab, size=branching, replace=False)
+        logit[v] = rng.normal(2.0, 0.5, branching)
+    return jnp.asarray(succ), jnp.asarray(logit)
 
 
-def lm_batch(seed: int, step: int, batch: int, seq: int, vocab: int,
-             table: jnp.ndarray | None = None):
-    """Markov stream: tokens[t+1] ~ softmax(table[tokens[t]])."""
-    if table is None:
-        table = make_markov_table(seed, vocab)
-    k = _key(seed, step)
-    k0, ks = jax.random.split(k)
+@functools.partial(jax.jit, static_argnames=("batch", "seq", "vocab"))
+def _markov_stream(key, succ, logit, *, batch: int, seq: int, vocab: int):
+    k0, ks = jax.random.split(key)
     first = jax.random.randint(k0, (batch,), 0, vocab)
+    rows = jnp.arange(batch)[:, None]
 
     def gen(tok, kt):
-        nxt = jax.random.categorical(kt, table[tok], axis=-1)
+        dense = jnp.full((batch, vocab), _MARKOV_FLOOR, jnp.float32)
+        dense = dense.at[rows, succ[tok]].set(logit[tok])
+        nxt = jax.random.categorical(kt, dense, axis=-1)
         return nxt, nxt
 
     keys = jax.random.split(ks, seq)
     _, toks = jax.lax.scan(gen, first, keys)
     toks = jnp.moveaxis(toks, 0, 1)                    # [B, S]
     tokens = jnp.concatenate([first[:, None], toks[:, :-1]], axis=1)
-    labels = toks
-    return {"tokens": tokens.astype(jnp.int32), "labels": labels.astype(jnp.int32)}
+    return {"tokens": tokens.astype(jnp.int32), "labels": toks.astype(jnp.int32)}
+
+
+def lm_batch(seed: int, step: int, batch: int, seq: int, vocab: int,
+             table=None):
+    """Markov stream: tokens[t+1] ~ softmax(row tokens[t] of the chain)."""
+    if table is None:
+        table = make_markov_table(seed, vocab)
+    succ, logit = table
+    return _markov_stream(_key(seed, step), succ, logit, batch=batch,
+                          seq=seq, vocab=vocab)
 
 
 def seq2seq_batch(seed: int, step: int, batch: int, src_len: int, tgt_len: int,

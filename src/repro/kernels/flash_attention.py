@@ -30,8 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import auto_interpret
 from repro.kernels.dispatch import pad_to_lane
-from repro.kernels.s2fp8_matmul import _dequant
+from repro.kernels.s2fp8_matmul import MXU_PRECISION, _dequant
 from repro.kernels.s2fp8_quant import _truncate_body
 
 _MASK_VALUE = -1e30
@@ -55,6 +56,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
     d = q.shape[-1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=MXU_PRECISION,
                             preferred_element_type=jnp.float32)
     s = s * (1.0 / jnp.sqrt(jnp.float32(d)))      # (bq, bk)
 
@@ -75,7 +77,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # (bq, bk)
     l_new = l_s[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
     l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
@@ -89,8 +92,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "window", "bq", "bk", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=None,
-                           bq=512, bk=512, interpret: bool = True):
+                           bq=512, bk=512, interpret: bool | None = None):
     """q: [B,H,Sq,D]; k,v: [B,H,Sk,D] (kv heads pre-broadcast). Returns like q."""
+    if interpret is None:
+        interpret = auto_interpret()
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bq = min(bq, sq)
@@ -171,6 +176,7 @@ def _qflash_fwd_kernel(qa, qb, ka, kb, va, vb, oa, ob,
     v = _dequant(v_ref[0], va[0, 0], vb[0, 0])     # (bk, d) f32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=MXU_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     mask = _attn_mask(iq, ik, bq, bk, sq, sk, causal, window)
     s = jnp.where(mask, s, _MASK_VALUE)
@@ -181,7 +187,8 @@ def _qflash_fwd_kernel(qa, qb, ka, kb, va, vb, oa, ob,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     l_new = l_s[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
     l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
@@ -190,7 +197,7 @@ def _qflash_fwd_kernel(qa, qb, ka, kb, va, vb, oa, ob,
         l_fin = l_s[:, :1]
         denom = jnp.where(l_fin == 0.0, 1.0, l_fin)
         acc = acc_s[...] / denom
-        lse_ref[0] = m_s[:, 0] + jnp.log(jnp.maximum(l_s[:, 0], 1e-30))
+        lse_ref[0] = m_s[:, :1] + jnp.log(jnp.maximum(l_fin, 1e-30))
         if epilogue:
             # fused Eq. 5 epilogue: the output tile leaves VMEM already in
             # the out site's representable set (s2fp8_matmul.py idiom)
@@ -214,20 +221,23 @@ def _qflash_dq_kernel(qa, qb, ka, kb, va, vb, ga, gb,
     k = _dequant(k_ref[0], ka[0, 0], kb[0, 0])
     v = _dequant(v_ref[0], va[0, 0], vb[0, 0])
     do = _dequant(g_ref[0], ga[0, 0], gb[0, 0])
-    lse = lse_ref[0]                               # (bq,)
-    dlt = del_ref[0]                               # (bq,)
+    lse = lse_ref[0]                               # (bq, 1)
+    dlt = del_ref[0]                               # (bq, 1)
 
     # score-tile recompute from the 1-byte payloads (no saved probs)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=MXU_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     mask = _attn_mask(iq, ik, bq, bk, sq, sk, causal, window)
     s = jnp.where(mask, s, _MASK_VALUE)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
 
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             precision=MXU_PRECISION,
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dlt[:, None]) * scale
+    ds = p * (dp - dlt) * scale
     acc_s[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                      precision=MXU_PRECISION,
                                       preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
@@ -256,17 +266,21 @@ def _qflash_dkdv_kernel(qa, qb, ka, kb, va, vb, ga, gb,
     dlt = del_ref[0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=MXU_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     mask = _attn_mask(iq, ik, bq, bk, sq, sk, causal, window)
     s = jnp.where(mask, s, _MASK_VALUE)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
 
     dv_s[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                     precision=MXU_PRECISION,
                                      preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             precision=MXU_PRECISION,
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dlt[:, None]) * scale
+    ds = p * (dp - dlt) * scale
     dk_s[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                     precision=MXU_PRECISION,
                                      preferred_element_type=jnp.float32)
 
     @pl.when(iq == nq - 1)
@@ -294,12 +308,11 @@ def qflash_fwd_pallas(qp, kp, vp, q_stats, k_stats, v_stats, *, g,
     `bh // g` index map — never materialized per head.  ``*_stats`` are
     the bank (alpha, beta) scalar pairs.  Ragged head dims are zero-padded
     to the 128-lane grid (exact for S2FP8); ``scale`` is the caller's true
-    1/sqrt(d).  Returns (out f32 [BH, Sq, d], lse f32 [BH, Sq]); when
+    1/sqrt(d).  Returns (out f32 [BH, Sq, d], lse f32 [BH, Sq, 1]); when
     ``out_stats`` is given the output tile gets the fused Eq. 5 truncation
     epilogue before leaving VMEM.
     """
     if interpret is None:
-        from repro.kernels import auto_interpret
         interpret = auto_interpret()
     bh, sq, d0 = qp.shape
     bkv, sk, _ = kp.shape
@@ -326,11 +339,11 @@ def qflash_fwd_pallas(qp, kp, vp, q_stats, k_stats, v_stats, *, g,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda h, iq, ik: (h, iq, 0)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -352,7 +365,9 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
 
     Residual inputs are the 1-byte Q/K/V payloads plus the quantized
     output cotangent ``gp`` [BH, Sq, d] and the rowwise ``lse``/``delta``
-    [BH, Sq] f32 vectors; score tiles are recomputed per (bq, bk) block.
+    [BH, Sq, 1] f32 columns; score tiles are recomputed per (bq, bk) block.
+    The trailing unit axis keeps their (1, bq, 1) blocks on the TPU's
+    (8, 128) tiling rule (a (1, bq) block over [BH, Sq] breaks it).
     The dq kernel accumulates over the sequential kv grid; the dk/dv
     kernel accumulates over the sequential q grid and emits PER-HEAD
     [BH, Sk, d] gradients (each output block written exactly once — the
@@ -360,7 +375,6 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
     Returns raw f32 (dq, dk_per_head, dv_per_head).
     """
     if interpret is None:
-        from repro.kernels import auto_interpret
         interpret = auto_interpret()
     bh, sq, d0 = qp.shape
     bkv, sk, _ = kp.shape
@@ -387,8 +401,8 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
             pl.BlockSpec((1, bk, d), lambda h, iq, ik: (h // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda h, iq, ik: (h // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda h, iq, ik: (h, iq, 0)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda h, iq, ik: (h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
@@ -405,8 +419,8 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
             pl.BlockSpec((1, bk, d), lambda h, ik, iq: (h // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda h, ik, iq: (h // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda h, ik, iq: (h, iq, 0)),
-            pl.BlockSpec((1, bq), lambda h, ik, iq: (h, iq)),
-            pl.BlockSpec((1, bq), lambda h, ik, iq: (h, iq)),
+            pl.BlockSpec((1, bq, 1), lambda h, ik, iq: (h, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda h, ik, iq: (h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda h, ik, iq: (h, ik, 0)),

@@ -35,7 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import auto_interpret
-from repro.kernels.s2fp8_matmul import _dequant
+from repro.kernels.s2fp8_matmul import MXU_PRECISION, _dequant
 
 _MASK_VALUE = -1e30
 
@@ -61,6 +61,7 @@ def _paged_kernel(table_ref, pos_ref, ka, kb, va, vb,
     v = _dequant(v_ref[0, 0], va[0, 0], vb[0, 0])
     d = q.shape[-1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=MXU_PRECISION,
                             preferred_element_type=jnp.float32)
     s = s * (1.0 / jnp.sqrt(jnp.float32(d)))          # (G, blk)
 
@@ -74,7 +75,8 @@ def _paged_kernel(table_ref, pos_ref, ka, kb, va, vb,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     l_new = l_s[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
     l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 

@@ -5,8 +5,11 @@ against (assert_allclose in tests/test_kernels.py).  No pallas imports.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import fp8, s2fp8
 
@@ -54,6 +57,10 @@ GEMM_CONTRACT = {
 }
 
 
+# The same layouts as einsum specs (for the accumulation bound below).
+GEMM_SPECS = {"nn": "mk,kn->mn", "nt": "mk,nk->mn", "tn": "km,kn->mn"}
+
+
 def gemm_dims(layout: str, a_shape, b_shape):
     """(m, k, n) of the logical GEMM for stored operand shapes."""
     if layout == "nn":
@@ -79,11 +86,52 @@ def s2fp8_matmul_ref(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
     those stats (the kernel's in-VMEM epilogue, expressed elementwise)."""
     a = s2fp8_dequant_ref(a_payload, a_alpha, a_beta)
     b = s2fp8_dequant_ref(b_payload, b_alpha, b_beta)
+    # f32 products on every platform (a TPU's default contraction rounds
+    # f32 operands to bf16), like the payload kernels' MXU_PRECISION
     y = jax.lax.dot_general(a, b, GEMM_CONTRACT[layout],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     if out_alpha is not None:
         y = s2fp8_truncate_ref(y, stats=(out_alpha, out_beta), fmt=fmt)
     return y
+
+
+_F32_UNIT_ROUNDOFF = 2.0 ** -24
+
+
+def dot_error_bound(spec: str, a, b) -> np.ndarray:
+    """Elementwise bound on ``|x - y|`` for two f32 evaluations x, y of
+    ``einsum(spec, a, b)`` that add the K products in different orders.
+
+    Each evaluation lies within ``gamma_K * (|a| . |b|)`` of the exact
+    value, ``gamma_K = K u / (1 - K u)``, ``u = 2^-24`` (Higham, Accuracy
+    and Stability of Numerical Algorithms, 2nd ed., eq. 3.5), whatever its
+    order, K-blocking or zero padding; two of them therefore differ by at
+    most twice that.  This is the tolerance between a tiled kernel and an
+    XLA dot: neither fixes the summation order (XLA:CPU's depends on the
+    operand shapes, so a lane-padded kernel tile sums differently from the
+    unpadded oracle).
+    """
+    ins, out = spec.split("->")
+    sa, sb = ins.split(",")
+    k = math.prod(np.shape(a)[sa.index(c)]
+                  for c in (set(sa) & set(sb)) - set(out))
+    gamma = k * _F32_UNIT_ROUNDOFF / (1.0 - k * _F32_UNIT_ROUNDOFF)
+    return 2.0 * gamma * np.einsum(spec, np.abs(np.asarray(a, np.float64)),
+                                   np.abs(np.asarray(b, np.float64)),
+                                   optimize=True)
+
+
+def truncation_bracket(y, bound, truncate):
+    """``(lo, hi)`` with ``lo <= truncate(x) <= hi`` for every f32 x within
+    ``bound`` of ``y``.  Eq. 5 truncation is monotone (positive alpha,
+    monotone maps, round-to-nearest), so the truncated interval endpoints
+    bracket it: where y sits farther than ``bound`` from a rounding
+    boundary, ``lo == hi`` and the comparison is exact."""
+    y = np.asarray(y, np.float64)
+    lo = np.nextafter(np.float32(y - bound), np.float32(-np.inf))
+    hi = np.nextafter(np.float32(y + bound), np.float32(np.inf))
+    return np.asarray(truncate(lo)), np.asarray(truncate(hi))
 
 
 # Batched variants: one leading batch axis on both operands, per-slice
@@ -126,6 +174,7 @@ def s2fp8_matmul_batched_ref(a_payload, a_alpha, a_beta,
     a = _expand_batch(s2fp8_dequant_ref(a_payload, a_alpha, a_beta), g)
     b = _expand_batch(s2fp8_dequant_ref(b_payload, b_alpha, b_beta), g)
     y = jax.lax.dot_general(a, b, GEMM_CONTRACT_BATCHED[layout],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     if go != g:
         y = y.reshape((g // go, go) + y.shape[1:]).sum(axis=0)

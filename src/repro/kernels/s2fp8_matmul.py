@@ -40,6 +40,14 @@ from repro.kernels import auto_interpret
 from repro.kernels.ref import GEMM_CONTRACT, GEMM_LAYOUTS, gemm_dims
 from repro.kernels.s2fp8_quant import _truncate_body
 
+# Dequantized tiles carry full 24-bit mantissas (the Eq. 4 inverse map is
+# not linear in the payload), and the MXU's default f32 contraction rounds
+# its operands to bf16: measured on a v5e, that put the payload GEMM up to
+# 4x outside the f32 accumulation bound of kernels/ref.py.  HIGHEST keeps
+# every product exact to f32, as the paper's FP32-accumulate GEMM (§5) and
+# the oracles assume.  Every dot of the payload kernels uses it.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _dequant(y, alpha, beta):
     y = y.astype(jnp.float32)
@@ -60,6 +68,7 @@ def _matmul_kernel(aa_ref, ab_ref, ba_ref, bb_ref, oa_ref, ob_ref,
     a = _dequant(a_ref[...], aa_ref[0, 0], ab_ref[0, 0])
     b = _dequant(b_ref[...], ba_ref[0, 0], bb_ref[0, 0])
     o_ref[...] += jax.lax.dot_general(a, b, GEMM_CONTRACT[layout],
+                                      precision=MXU_PRECISION,
                                       preferred_element_type=jnp.float32)
     if epilogue:
         @pl.when(k == pl.num_programs(2) - 1)
@@ -100,6 +109,7 @@ def _batched_matmul_kernel(aa_ref, ab_ref, ba_ref, bb_ref, oa_ref, ob_ref,
     a = _dequant(a_ref[...][0], aa_ref[0, 0], ab_ref[0, 0])
     b = _dequant(b_ref[...][0], ba_ref[0, 0], bb_ref[0, 0])
     o_ref[...] += jax.lax.dot_general(a, b, GEMM_CONTRACT[layout],
+                                      precision=MXU_PRECISION,
                                       preferred_element_type=jnp.float32
                                       )[None]
     if epilogue:

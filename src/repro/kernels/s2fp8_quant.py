@@ -5,7 +5,7 @@ The paper (§5) describes two HW components: (1) a statistics unit computing
 before the 8-bit truncation.  On TPU these become:
 
   * ``stats``  — a blocked reduction over the tensor resident in HBM,
-    streamed through VMEM tiles; partials accumulate in a (1,1) VMEM cell
+    streamed through VMEM tiles; partials accumulate in (1,1) SMEM cells
     across the sequential grid (TPU grid iterations run in order on a core).
   * ``apply``  — an elementwise VPU map: y = sign(x)*2^(alpha*log2|x|+beta),
     cast RNE to float8_e5m2 in-register, written back as the 1-byte payload.
@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.s2fp8 import (FMT_MAX_FINITE, FMT_QDTYPE, FMT_TARGET_MAX,
                               TARGET_MAX_LOG2, stats_from_reduction)
@@ -41,6 +42,9 @@ from repro.kernels import auto_interpret
 
 DEFAULT_BLOCK = (256, 512)
 _NEG_INF = -jnp.inf
+# Scalar accumulators live in SMEM, whole-array and resident across the
+# sequential grid: Mosaic stores scalars to SMEM only, never to VMEM.
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _resolve(interpret):
@@ -160,12 +164,11 @@ def stats_pallas(x: jnp.ndarray, *, block=DEFAULT_BLOCK, interpret: bool | None 
     m, n = x.shape
     bm, bn = min(block[0], m), min(block[1], n)
     grid = (m // bm, n // bn)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     s, mx, c = pl.pallas_call(
         _stats_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
-        out_specs=[scalar_spec, scalar_spec, scalar_spec],
+        out_specs=[_SMEM_SPEC] * 3,
         out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32)] * 3,
         interpret=interpret,
     )(x)
@@ -274,7 +277,7 @@ def truncate_fused_pallas(x: jnp.ndarray, *, fmt: str = "e5m2",
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda p, i, j: (i, j))],
         out_specs=[pl.BlockSpec((bm, bn), lambda p, i, j: (i, j)),
-                   pl.BlockSpec((1, 3), lambda p, i, j: (0, 0))],
+                   _SMEM_SPEC],
         out_shape=[jax.ShapeDtypeStruct((m, n), jnp.float32),
                    jax.ShapeDtypeStruct((1, 3), jnp.float32)],
         interpret=interpret,

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import auto_interpret
+
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
                  y_ref, hout_ref, *, seq_len):
@@ -56,9 +58,11 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def selective_scan_pallas(x, dt, bmat, cmat, a, d_skip, *,
-                          block_d: int = 256, interpret: bool = True):
+                          block_d: int = 256, interpret: bool | None = None):
     """x, dt: [B,S,di]; bmat, cmat: [B,S,n]; a: [di,n]; d_skip: [di].
     Returns (y [B,S,di], h_final [B,di,n])."""
+    if interpret is None:
+        interpret = auto_interpret()
     b, s, di = x.shape
     n = bmat.shape[-1]
     bd = min(block_d, di)

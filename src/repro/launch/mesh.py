@@ -14,13 +14,21 @@ before any builder runs (ROADMAP: multi-host FSDP remainder).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    # Auto axes: the models place activations with logical-axis sharding
+    # constraints (parallel/sharding.py), which jax refuses on the
+    # Explicit axes jax.make_mesh now defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh():
@@ -31,7 +39,7 @@ def make_host_mesh():
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` CPU smoke
     runs) out of the box."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _make_mesh((n, 1), ("data", "model"))
 
 
 def make_mesh_from_spec(spec: str):
@@ -52,7 +60,7 @@ def make_mesh_from_spec(spec: str):
     else:
         raise ValueError(f"mesh spec {spec!r}: want 2 (DxT) or 3 (PxDxT) "
                          f"factors, got {len(dims)}")
-    return jax.make_mesh(dims, axes)
+    return _make_mesh(dims, axes)
 
 
 def axis_sizes(mesh) -> dict:

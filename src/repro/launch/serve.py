@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs.base import get_config, get_reduced_config
 from repro.core.policy import make_policy
 from repro.launch import api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.sinks import make_sink
 from repro.serving import bank as sbank
 from repro.serving import paged_cache
@@ -50,6 +51,7 @@ def main():
                     help="per-tick metrics sink spec (obs.sinks.make_sink)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.enc_dec:

@@ -12,11 +12,10 @@ the dry-run proves out (launch/dryrun.py).
 from __future__ import annotations
 
 import argparse
-import functools
-import os
+import contextlib
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import get_config, get_reduced_config
 from repro.core import backend as nbackend
@@ -26,6 +25,7 @@ from repro.core.policy import make_policy
 from repro.checkpoint.manager import CheckpointManager
 from repro.data import synthetic
 from repro.launch import api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (axis_sizes, make_host_mesh,
                                make_mesh_from_spec, make_production_mesh)
 from repro import obs
@@ -37,7 +37,9 @@ from repro.training import trainer as trainer_mod
 from repro.training.trainer import TrainLoop, make_train_step
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's options.  ``--arch``/``--reduced`` pick the config in
+    :func:`main`; everything else is read by :func:`train`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -142,9 +144,14 @@ def main():
                          "inf_loss, reject, saturating_bank, "
                          "corrupt_ckpt, slow_step, corrupt_batch. "
                          "Implies --guard.")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
+
+def train(cfg, args) -> TrainLoop:
+    """Train ``cfg`` for ``args.steps`` steps under the options parsed by
+    :func:`build_parser` and return the finished :class:`TrainLoop`
+    (``loop.history`` holds the per-step metrics, ``step_s`` among them).
+    """
     backend_name = args.backend or getattr(cfg, "numerics_backend", "auto")
     pol = make_policy(args.policy, loss_scale=args.loss_scale,
                       backend=backend_name, gemm_mode=args.gemm_mode)
@@ -248,7 +255,6 @@ def main():
         return synthetic.lm_batch(args.seed, step, args.batch, args.seq,
                                   cfg.vocab, table)
 
-    import contextlib
     mesh_ctx = mesh if mesh is not None else contextlib.nullcontext()
     rules_ctx = (shd.use_rules(shd.TRAIN_RULES, sizes) if mesh is not None
                  else contextlib.nullcontext())
@@ -261,6 +267,23 @@ def main():
                                        stats_cfg)
             print(f"[train] statsbank: {len(bank)} sites, refresh every "
                   f"{stats_cfg.refresh_every} steps, ema {stats_cfg.ema_decay}")
+        if mesh is not None:
+            # lay the carry out as the step returns it, so that step 0 and
+            # every later step run one compiled program
+            specs = shd.train_step_specs(
+                data_fn(0), mesh, with_stats=bank is not None,
+                param_sharding=args.shard_params, params=params,
+                opt_state=opt_state)[0]
+
+            def place(tree, spec):
+                return jax.device_put(tree, jax.tree_util.tree_map(
+                    lambda p: NamedSharding(mesh, p), spec,
+                    is_leaf=lambda p: isinstance(p, PartitionSpec)))
+
+            params, opt_state = place(params, specs[0]), place(opt_state,
+                                                               specs[1])
+            if bank is not None:
+                bank = place(bank, specs[2])
         ckpt = None
         if args.ckpt_dir:
             ckpt = CheckpointManager(
@@ -279,9 +302,17 @@ def main():
                          watchdog_escalate_after=args.watchdog_escalate_after)
         if args.resume == "auto" and ckpt is not None:
             loop.maybe_resume()
-        history = loop.run(args.steps)
+        loop.run(args.steps)
     if sink is not None:
         sink.close()
+    return loop
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    history = train(cfg, args).history
     final = history[-1] if history else {}
     print(f"[train] done: final loss {final.get('loss'):.4f}")
 

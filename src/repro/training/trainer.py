@@ -33,7 +33,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import io_callback
-from jax.experimental.shard_map import shard_map
 
 from repro.core import collectives
 from repro.core import s2fp8
@@ -265,7 +264,7 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
 
     def _make_reduce_metrics(int_div: int):
         # every metric leaf must leave the shard_map replicated (out_specs
-        # P() with check_rep=False would silently report shard 0's local
+        # P() with check_vma=False would silently report shard 0's local
         # value otherwise): float leaves psum to the global MEAN of the
         # per-shard means, integer leaves (counts) psum to the global SUM
         # — divided back by the shard count when the batch took the
@@ -487,8 +486,8 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
             batch, mesh, with_stats=stats is not None,
             with_guard=guard is not None, param_sharding=param_sharding,
             params=args[0], opt_state=args[1])
-        out = shard_map(bodies[key], mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)(*args)
+        out = jax.shard_map(bodies[key], mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(*args)
         if stats is not None:
             _drain_telemetry(out[2], args[-1])
         return out
@@ -689,7 +688,9 @@ class TrainLoop:
         step = self.start_step
         while step < steps:
             t_fetch = time.perf_counter()
-            batch = self.data_fn(step)
+            # the data may be made by a jitted program: wait for it here,
+            # so that its time counts as data_s and not as step time
+            batch = jax.block_until_ready(self.data_fn(step))
             data_s = time.perf_counter() - t_fetch
             if self.chaos is not None:
                 mutated = self.chaos.mutate_bank(step, self.stats_bank)
@@ -707,6 +708,7 @@ class TrainLoop:
             dt = time.perf_counter() - t0
             metrics = {k: (float(v) if hasattr(v, "item") and getattr(v, 'ndim', 1) == 0 else v)
                        for k, v in metrics.items()}
+            metrics["step_s"], metrics["data_s"] = dt, data_s
             # straggler watchdog: flag steps > factor x trailing median
             event = watchdog.observe(step, dt)
             if event is not None:

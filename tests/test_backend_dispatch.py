@@ -80,24 +80,65 @@ def test_truncate_degenerate_tensors():
     np.testing.assert_array_equal(c, r)
 
 
-def test_policy_s2fp8_pallas_bitwise_identical_to_ref():
-    """Policy(mode='s2fp8') routed through the pallas backend: identical
-    GEMM results and identical truncated cotangents, bit for bit."""
+def _payload_policy_matches_ref_chain(got, x, w, layout):
+    """``got`` is the pallas payload policy's GEMM of ``x`` and ``w``.
+    Its operand payloads dequantize to the ref truncations bit for bit;
+    its raw GEMM lies within the f32 accumulation-order bound of the ref
+    chain's XLA dot (the kernel sums K over a lane-padded tile); and
+    ``got`` is the ref truncation of that raw GEMM under its own exact
+    stats, bit for bit, hence inside the truncation bracket of the ref
+    dot."""
+    rb, pb = nbackend.get_backend("ref"), nbackend.get_backend("pallas")
+    qx, qw = pb.quantize(x), pb.quantize(w)
+    tx, tw = rb.truncate(x), rb.truncate(w)
+    np.testing.assert_array_equal(np.asarray(pb.dequantize(qx)),
+                                  np.asarray(tx))
+    np.testing.assert_array_equal(np.asarray(pb.dequantize(qw)),
+                                  np.asarray(tw))
+    raw = pb.qmatmul(qx, qw, layout=layout)
+    fig4 = jax.lax.dot_general(tx, tw, ref.GEMM_CONTRACT[layout],
+                               preferred_element_type=jnp.float32)
+    bound = ref.dot_error_bound(ref.GEMM_SPECS[layout], tx, tw)
+    assert (np.abs(np.asarray(raw) - np.asarray(fig4)) <= bound).all()
+    so = rb.compute_stats(raw)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(rb.truncate(raw, stats=so)))
+    lo, hi = ref.truncation_bracket(
+        fig4, bound, lambda y: rb.truncate(jnp.asarray(y), stats=so))
+    assert ((lo <= got) & (got <= hi)).all()
+
+
+@pytest.mark.parametrize("gemm_mode", ["fig4", "auto"])
+def test_policy_s2fp8_pallas_bitwise_identical_to_ref(gemm_mode):
+    """Policy(mode='s2fp8') routed through the pallas backend vs the ref
+    backend: GEMM results and truncated cotangents, eager and jitted.
+
+    ``fig4``: both sides take the Fig. 4 chain, so the GEMM between the
+    truncations is the same XLA dot and every result is identical bit
+    for bit.  ``auto`` resolves to the payload GEMM on pallas: identical
+    to the ref chain bit for bit given its raw GEMM, which differs from
+    the XLA dot only in summation order
+    (:func:`_payload_policy_matches_ref_chain`)."""
     a = jax.random.normal(jax.random.PRNGKey(2), (66, 34)) * 1e-7
     b = jax.random.normal(jax.random.PRNGKey(3), (34, 18)) * 1e-7
     pr = make_policy("s2fp8", backend="ref")
-    pp = make_policy("s2fp8", backend="pallas")
-    np.testing.assert_array_equal(np.asarray(pp.dot(a, b)),
-                                  np.asarray(pr.dot(a, b)))
+    pp = make_policy("s2fp8", backend="pallas", gemm_mode=gemm_mode)
+    assert pp.uses_payload_gemm == (gemm_mode == "auto")
     cot = jax.random.normal(jax.random.PRNGKey(4), (66, 18)) * 1e-9
+    y = np.asarray(pp.dot(a, b))
     _, vr = jax.vjp(lambda a_: pr.dot(a_, b), a)
     _, vp = jax.vjp(lambda a_: pp.dot(a_, b), a)
-    np.testing.assert_array_equal(np.asarray(vp(cot)[0]),
-                                  np.asarray(vr(cot)[0]))
-    # and under jit
+    da = np.asarray(vp(cot)[0])
     f = jax.jit(lambda a_, b_: pp.dot(a_, b_))
-    np.testing.assert_array_equal(np.asarray(f(a, b)),
-                                  np.asarray(pr.dot(a, b)))
+    if gemm_mode == "fig4":
+        np.testing.assert_array_equal(y, np.asarray(pr.dot(a, b)))
+        np.testing.assert_array_equal(da, np.asarray(vr(cot)[0]))
+    else:
+        _payload_policy_matches_ref_chain(y, a, b, "nn")
+        # dA = GEMM(g, B^T) on the truncated cotangent and B payload
+        _payload_policy_matches_ref_chain(da, cot, b, "nt")
+    # and under jit: the same kernels, the same bits
+    np.testing.assert_array_equal(np.asarray(f(a, b)), y)
 
 
 def test_fused_stats_mode_float_parity():

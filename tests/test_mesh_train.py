@@ -308,7 +308,6 @@ import os, sys, json, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 import mesh_toy
 from repro.checkpoint.manager import CheckpointManager
@@ -362,9 +361,9 @@ def body(gl):
     c, nrm = optimizers.clip_by_global_norm(gl, 1.0, axis_name="data")
     return c, nrm[None]
 
-sh_c, sh_n = shard_map(body, mesh=mesh, in_specs=P("data"),
-                       out_specs=(P("data"), P("data")),
-                       check_rep=False)(g)
+sh_c, sh_n = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                           out_specs=(P("data"), P("data")),
+                           check_vma=False)(g)
 out["clip_values_bitwise"] = bitwise(sh_c, full_c)
 out["clip_norm_bitwise"] = bool((np.asarray(sh_n) == float(full_n)).all())
 print("RESULT " + json.dumps(out))

@@ -36,6 +36,39 @@ def test_fp8_matches_paper_table_a1():
     assert float(fp8.truncate_e5m2(jnp.float32(1e-30))) == 0.0
 
 
+def _fp8_sweep(qdtype):
+    """Random f32 bit patterns plus every grid point of ``qdtype``, every
+    midpoint between neighbours (the ties) and the specials."""
+    rng = np.random.default_rng(0)
+    x = rng.integers(0, 2 ** 32, 200_000, dtype=np.uint64).astype(
+        np.uint32).view(np.float32)
+    grid = np.arange(256, dtype=np.uint8).view(qdtype).astype(np.float64)
+    grid = np.unique(grid[np.isfinite(grid)])
+    mids = (grid[1:] + grid[:-1]) / 2
+    near = np.concatenate([np.nextafter(mids, -np.inf),
+                           np.nextafter(mids, np.inf)])
+    special = [0.0, -0.0, np.inf, -np.inf, np.nan, 61440.0, 464.0, 1e-30]
+    return np.concatenate([x] + [np.asarray(a, np.float32)
+                                 for a in (grid, mids, near, special)])
+
+
+@pytest.mark.parametrize("fn,qdtype", [
+    (fp8.truncate_e5m2, jnp.float8_e5m2),
+    (fp8.truncate_e4m3, jnp.float8_e4m3fn),
+])
+def test_fp8_truncate_bitwise_equals_cast_round_trip(fn, qdtype):
+    """The f32-arithmetic rounding is the FP8 cast round trip, bit for bit,
+    over subnormals, ties, overflow, infinities and NaN (the sign of zero
+    included)."""
+    x = _fp8_sweep(qdtype)
+    want = x.astype(qdtype).astype(np.float32)
+    got = np.asarray(jax.jit(fn)(jnp.asarray(x)))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
 def test_stats_satisfy_eq2():
     """alpha/beta must give log2|Y| zero-mean and max exactly 15 (Eq. 2)."""
     key = jax.random.PRNGKey(0)

@@ -438,7 +438,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core import backend as nbackend
 from repro.core import statsbank
 
@@ -462,8 +461,8 @@ def stats_body(xl):
     a, b = be.compute_stats(xl, axis_name="data")
     return a[None], b[None]
 
-a2, b2 = shard_map(stats_body, mesh=mesh, in_specs=P("data"),
-                   out_specs=P("data"), check_rep=False)(x)
+a2, b2 = jax.shard_map(stats_body, mesh=mesh, in_specs=P("data"),
+                           out_specs=P("data"), check_vma=False)(x)
 out["stats_alpha_bitwise"] = bool((np.asarray(a2) == float(a1)).all())
 out["stats_beta_bitwise"] = bool((np.asarray(b2) == float(b1)).all())
 
@@ -477,8 +476,8 @@ def refresh_body(xl):
                                  axis_name="data")
     return {k: v[None] for k, v in st.items()}
 
-sh_st = shard_map(refresh_body, mesh=mesh, in_specs=P("data"),
-                  out_specs=P("data"), check_rep=False)(x)
+sh_st = jax.shard_map(refresh_body, mesh=mesh, in_specs=P("data"),
+                      out_specs=P("data"), check_vma=False)(x)
 for k in statsbank.STATE_FIELDS:
     out[f"refresh_{k}_bitwise"] = bool(
         (np.asarray(sh_st[k]) == float(ref_st[k])).all())
