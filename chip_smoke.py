@@ -33,7 +33,6 @@ import json
 import math
 import sys
 import time
-from collections import defaultdict
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -47,8 +46,6 @@ REFRESH_EVERY = 4
 # loss agreement of the compressed-sync / fsdp_q run with the f32 /
 # replicated run: the bound of the 8-way CPU tests (tests/test_mesh_train.py)
 LOSS_GAP = 0.15
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def log(msg: str) -> None:
@@ -66,26 +63,12 @@ def check(ok, what: str) -> None:
         print(f"[smoke] FAILED: {what}", file=sys.stderr, flush=True)
 
 
-class CompileClock:
-    """Seconds XLA spends compiling (or reading the persistent cache)."""
-
-    def __init__(self):
-        import jax
-        self.secs = defaultdict(float)
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw):
-        if event == _COMPILE_EVENT:
-            self.secs[kw.get("fun_name", "?")] += secs
-
-    def _event(self, event, **kw):
-        if event == _CACHE_HIT_EVENT:
-            self.hits += 1
-
-    def total(self) -> float:
-        return sum(self.secs.values())
+def compile_clock():
+    """(seconds XLA spent compiling or reading the persistent cache,
+    persistent-cache hits) so far, from the program's recorder."""
+    from repro.obs import spans
+    rec = spans.RECORDER
+    return rec.total(spans.COMPILE), rec.counters.get(spans.CACHE_HITS, 0)
 
 
 def smoke_config():
@@ -349,8 +332,8 @@ def losses_of(loop, steps: int) -> list:
     return losses
 
 
-def train_one_chip(cfg, batch: int, seq: int, steps: int, seed: int,
-                   clock: CompileClock) -> None:
+def train_one_chip(cfg, batch: int, seq: int, steps: int, seed: int
+                   ) -> None:
     import jax
     import jax.numpy as jnp
     from repro.core import backend as nbackend
@@ -358,13 +341,14 @@ def train_one_chip(cfg, batch: int, seq: int, steps: int, seed: int,
     from repro.launch.train import train
 
     args = train_args(batch, seq, steps, seed, ("--mesh", "none"))
-    before = clock.total()
+    before = compile_clock()[0]
     t0 = time.perf_counter()
     loop = train(cfg, args)
     wall = time.perf_counter() - t0
     losses_of(loop, steps)
-    log(f"train phase: {wall:.2f} s wall, compile {clock.total() - before:.2f}"
-        f" s, persistent-cache hits {clock.hits}")
+    secs, hits = compile_clock()
+    log(f"train phase: {wall:.2f} s wall, compile {secs - before:.2f}"
+        f" s, persistent-cache hits {hits}")
 
     # the step the loop ran, compiled again for inspection (a cache read
     # when the persistent cache holds it)
@@ -392,8 +376,7 @@ def train_one_chip(cfg, batch: int, seq: int, steps: int, seed: int,
 # four chips: compressed sync + quantized FSDP vs the f32 / replicated step
 # ---------------------------------------------------------------------------
 
-def train_four_chips(cfg, seq: int, steps: int, seed: int,
-                     clock: CompileClock) -> None:
+def train_four_chips(cfg, seq: int, steps: int, seed: int) -> None:
     from repro.launch.train import train
     curves = {}
     for name, extra in (("f32/replicated", ("--grad-sync", "f32",
@@ -402,9 +385,9 @@ def train_four_chips(cfg, seq: int, steps: int, seed: int,
                                           "--shard-params", "fsdp_q"))):
         args = train_args(MESH_BATCH, seq, steps, seed,
                           ("--mesh", "4x1", *extra))
-        before = clock.total()
+        before = compile_clock()[0]
         loop = train(cfg, args)
-        log(f"{name}: compile {clock.total() - before:.2f} s")
+        log(f"{name}: compile {compile_clock()[0] - before:.2f} s")
         curves[name] = losses_of(loop, steps)
     ref_l, cmp_l = curves["f32/replicated"], curves["s2fp8/fsdp_q"]
     gaps = [abs(c - r) / abs(r) for c, r in zip(cmp_l, ref_l)]
@@ -434,20 +417,22 @@ def main() -> None:
 
     from repro.launch.compile_cache import enable_compile_cache
     log(f"compile cache: {enable_compile_cache()}")
-    clock = CompileClock()
+    from repro.obs import spans
+    spans.install_compile_listener()
     cfg = smoke_config()
     log(f"{cfg.name} cut to {cfg.n_layers} layers {cfg.pattern}: "
         f"{cfg.n_params() / 1e6:.1f} M params")
     t0 = time.perf_counter()
     if args.chips == 4:
         log(f"batch {MESH_BATCH} x seq {SEQ} over a 4x1 data mesh")
-        train_four_chips(cfg, SEQ, STEPS, args.seed, clock)
+        train_four_chips(cfg, SEQ, STEPS, args.seed)
     else:
         log(f"batch {BATCH} x seq {SEQ}")
         oracle_checks(cfg, BATCH, SEQ, args.seed)
-        train_one_chip(cfg, BATCH, SEQ, STEPS, args.seed, clock)
-    log(f"total compile {clock.total():.2f} s, persistent-cache hits "
-        f"{clock.hits}, wall {time.perf_counter() - t0:.2f} s")
+        train_one_chip(cfg, BATCH, SEQ, STEPS, args.seed)
+    secs, hits = compile_clock()
+    log(f"total compile {secs:.2f} s, persistent-cache hits {hits}, wall "
+        f"{time.perf_counter() - t0:.2f} s")
     if FAILED:
         raise SystemExit(f"[smoke] {len(FAILED)} check(s) failed: {FAILED}")
     print(json.dumps({"ok": True, "device": {

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.core import s2fp8
 from repro.core.s2fp8 import S2FP8Tensor
+from repro.obs import spans
 
 _TARGET_MAX = s2fp8.FMT_TARGET_MAX
 
@@ -384,12 +385,14 @@ class RefBackend(NumericsBackend):
     def compute_stats_partials(self, x):
         return s2fp8.compute_stats_partials_jit(x)
 
+    @jax.named_scope(spans.S2FP8_QUANTIZE)
     def quantize(self, x, *, stats=None, fmt: str = "e5m2"):
         return s2fp8.quantize(x, stats=stats, fmt=fmt)
 
     def dequantize(self, t, dtype=jnp.float32):
         return s2fp8.dequantize(t, dtype)
 
+    @jax.named_scope(spans.S2FP8_QUANTIZE)
     def truncate(self, x, *, stats=None, fmt: str = "e5m2"):
         if stats is None:
             stats = self.compute_stats(x, fmt=fmt)
@@ -463,6 +466,7 @@ class PallasBackend(NumericsBackend):
         return dispatch.stats_partials_nd(x, block=self.block,
                                           interpret=self.interpret)
 
+    @jax.named_scope(spans.S2FP8_QUANTIZE)
     def quantize(self, x, *, stats=None, fmt: str = "e5m2"):
         from repro.kernels import dispatch
         # exact mode: stats from the shared compiled reduction, so stored
@@ -480,6 +484,7 @@ class PallasBackend(NumericsBackend):
         return dispatch.dequant_nd(t.payload, t.alpha, t.beta, dtype=dtype,
                                    block=self.block, interpret=self.interpret)
 
+    @jax.named_scope(spans.S2FP8_QUANTIZE)
     def truncate(self, x, *, stats=None, fmt: str = "e5m2"):
         from repro.kernels import dispatch
         # stats=None + fused_stats=False -> truncate_nd's default branch

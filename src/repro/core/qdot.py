@@ -68,6 +68,7 @@ from repro.core import s2fp8
 from repro.core import statsbank
 from repro.core.backend import QdotPlan
 from repro.kernels import flash_attention as _fkern
+from repro.obs import spans
 
 # Backward GEMM table: forward layout -> ((dA lhs, dA rhs, dA layout),
 # (dB lhs, dB rhs, dB layout)) with operands named from {"a", "b", "g"}
@@ -107,6 +108,7 @@ def _epilogue_qmatmul(qa, qb, layout, st, pred_f, step_f, cfg, fmt,
     be = nbackend.get_backend(backend)
     need = jnp.logical_or(pred_f > 0, st["last"] < 0)
 
+    @jax.named_scope(spans.STATSBANK_REFRESH)
     def _refresh(_):
         y_raw = _qmm(be, qa, qb, layout, out_batch=out_batch, fmt=fmt)
         new = statsbank.refresh_state(
@@ -475,6 +477,7 @@ def _qflash_banked(backend: Optional[str], fmt: str,
         st = entry["out.fwd"]
         need = jnp.logical_or(pred_f > 0, st["last"] < 0)
 
+        @jax.named_scope(spans.STATSBANK_REFRESH)
         def _refresh(_):
             raw, lse = _payload_flash_fwd(be, qq, qk, qv, causal, window,
                                           fmt, bq, bk, None)

@@ -76,6 +76,7 @@ import jax.numpy as jnp
 from repro.core import backend as nbackend
 from repro.core import s2fp8
 from repro.obs import metrics as obs_metrics
+from repro.obs import spans
 
 STATE_FIELDS = ("alpha", "beta", "ema_mu", "ema_m", "last")
 
@@ -228,6 +229,7 @@ def maybe_refresh(x, state, pred_f, step_f, cfg: StatsConfig,
     the host-side cadence semantics."""
     need = jnp.logical_or(pred_f > 0, state["last"] < 0)
 
+    @jax.named_scope(spans.STATSBANK_REFRESH)
     def do(operand):
         x_, st = operand
         new = refresh_state(x_, st, step_f, ema_decay=cfg.ema_decay,
@@ -567,7 +569,8 @@ def init_bank(loss_fn: Callable, params, batch, policy,
     reports its key and whether it sits inside a scanned layer segment;
     segment sites get [L]-stacked state rows.  Site keys are a function of
     Python execution order, which is identical between this abstract trace
-    and the jitted train step.
+    and the jitted train step.  The discovery is timed as the host span
+    ``statsbank/init_bank`` (``repro.obs.spans``).
     """
     if current_session() is not None:
         raise RuntimeError("cannot run discovery inside an active session")
@@ -581,7 +584,8 @@ def init_bank(loss_fn: Callable, params, batch, policy,
             _ACTIVE.session = None
         return loss
 
-    jax.eval_shape(probe, params, batch)
+    with spans.span(spans.INIT_BANK):
+        jax.eval_shape(probe, params, batch)
     bank: Dict[str, Any] = {}
     for key, info in sess.recorded.items():
         length = (sess.segment_lengths.get(info["segment"])

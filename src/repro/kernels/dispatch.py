@@ -27,6 +27,7 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import s2fp8
@@ -38,6 +39,7 @@ from repro.kernels.s2fp8_quant import (DEFAULT_BLOCK, dequant_pallas,
                                        quant_apply_pallas, quant_pallas,
                                        stats_pallas, truncate_apply_pallas,
                                        truncate_fused_pallas)
+from repro.obs import spans
 
 # Lane width for the flattened layout of non-2-D tensors.
 LANE = 512
@@ -53,6 +55,7 @@ def _ceil_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+@jax.named_scope(spans.S2FP8_QUANTIZE)
 def _pad_axis(x: jnp.ndarray, axis: int, target: int) -> jnp.ndarray:
     pad = target - x.shape[axis]
     if pad == 0:

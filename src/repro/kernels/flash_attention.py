@@ -34,6 +34,7 @@ from repro.kernels import auto_interpret
 from repro.kernels.dispatch import pad_to_lane
 from repro.kernels.s2fp8_matmul import MXU_PRECISION, _dequant
 from repro.kernels.s2fp8_quant import _truncate_body
+from repro.obs import spans
 
 _MASK_VALUE = -1e30
 
@@ -109,6 +110,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
                                causal=causal, window=window)
     out = pl.pallas_call(
         kernel,
+        name=spans.FLASH,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
@@ -331,6 +333,7 @@ def qflash_fwd_pallas(qp, kp, vp, q_stats, k_stats, v_stats, *, g,
     scal = pl.BlockSpec((1, 1), lambda h, iq, ik: (0, 0))
     out, lse = pl.pallas_call(
         kernel,
+        name=spans.QFLASH_FWD,
         grid=(bh, sq // bq, sk // bk),
         in_specs=[scal] * 8 + [
             pl.BlockSpec((1, bq, d), lambda h, iq, ik: (h, iq, 0)),
@@ -395,6 +398,7 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
     scal_q = pl.BlockSpec((1, 1), lambda h, iq, ik: (0, 0))
     dq = pl.pallas_call(
         functools.partial(_qflash_dq_kernel, **common),
+        name=spans.QFLASH_BWD_DQ,
         grid=(bh, sq // bq, sk // bk),
         in_specs=[scal_q] * 8 + [
             pl.BlockSpec((1, bq, d), lambda h, iq, ik: (h, iq, 0)),
@@ -413,6 +417,7 @@ def qflash_bwd_pallas(qp, kp, vp, gp, q_stats, k_stats, v_stats, g_stats,
     scal_k = pl.BlockSpec((1, 1), lambda h, ik, iq: (0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_qflash_dkdv_kernel, **common),
+        name=spans.QFLASH_BWD_DKDV,
         grid=(bh, sk // bk, sq // bq),
         in_specs=[scal_k] * 8 + [
             pl.BlockSpec((1, bq, d), lambda h, ik, iq: (h, iq, 0)),

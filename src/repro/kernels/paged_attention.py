@@ -36,6 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import auto_interpret
 from repro.kernels.s2fp8_matmul import MXU_PRECISION, _dequant
+from repro.obs import spans
 
 _MASK_VALUE = -1e30
 
@@ -129,6 +130,7 @@ def paged_decode_attention(q, kp, vp, k_alpha, k_beta, v_alpha, v_beta,
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, blk=blk),
+        name=spans.PAGED_DECODE,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
         interpret=interpret,

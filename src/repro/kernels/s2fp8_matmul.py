@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 from repro.kernels import auto_interpret
 from repro.kernels.ref import GEMM_CONTRACT, GEMM_LAYOUTS, gemm_dims
 from repro.kernels.s2fp8_quant import _truncate_body
+from repro.obs import spans
 
 # Dequantized tiles carry full 24-bit mantissas (the Eq. 4 inverse map is
 # not linear in the payload), and the MXU's default f32 contraction rounds
@@ -221,6 +222,7 @@ def s2fp8_matmul_pallas(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
     return pl.pallas_call(
         functools.partial(_matmul_kernel, layout=layout, epilogue=epilogue,
                           fmt=fmt),
+        name=spans.GEMM,
         grid=grid,
         in_specs=[scalar] * 6 + [a_spec, b_spec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -274,6 +276,7 @@ def s2fp8_matmul_batched_pallas(a_payload, a_alpha, a_beta,
     return pl.pallas_call(
         functools.partial(_batched_matmul_kernel, layout=layout,
                           epilogue=epilogue, fmt=fmt),
+        name=spans.GEMM_BATCHED,
         grid=grid,
         in_specs=[scalar] * 6 + [a_spec, b_spec],
         out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, gr, kk: (gi, i, j)),

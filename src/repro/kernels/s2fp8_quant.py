@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.s2fp8 import (FMT_MAX_FINITE, FMT_QDTYPE, FMT_TARGET_MAX,
                               TARGET_MAX_LOG2, stats_from_reduction)
 from repro.kernels import auto_interpret
+from repro.obs import spans
 
 DEFAULT_BLOCK = (256, 512)
 _NEG_INF = -jnp.inf
@@ -166,6 +167,7 @@ def stats_pallas(x: jnp.ndarray, *, block=DEFAULT_BLOCK, interpret: bool | None 
     grid = (m // bm, n // bn)
     s, mx, c = pl.pallas_call(
         _stats_kernel,
+        name=spans.STATS,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=[_SMEM_SPEC] * 3,
@@ -186,6 +188,7 @@ def quant_apply_pallas(x: jnp.ndarray, alpha, beta, *, fmt: str = "e5m2",
     scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     return pl.pallas_call(
         functools.partial(_apply_kernel, fmt=fmt),
+        name=spans.QUANT_APPLY,
         grid=grid,
         in_specs=[scalar_spec, scalar_spec,
                   pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
@@ -219,6 +222,7 @@ def dequant_pallas(payload, alpha, beta, *, block=DEFAULT_BLOCK,
     scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     return pl.pallas_call(
         _dequant_kernel,
+        name=spans.DEQUANT,
         grid=grid,
         in_specs=[scalar_spec, scalar_spec,
                   pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
@@ -242,6 +246,7 @@ def truncate_apply_pallas(x: jnp.ndarray, alpha, beta, *, fmt: str = "e5m2",
     scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     return pl.pallas_call(
         functools.partial(_truncate_kernel, fmt=fmt),
+        name=spans.TRUNCATE_APPLY,
         grid=grid,
         in_specs=[scalar_spec, scalar_spec,
                   pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
@@ -274,6 +279,7 @@ def truncate_fused_pallas(x: jnp.ndarray, *, fmt: str = "e5m2",
     out, stats = pl.pallas_call(
         functools.partial(_truncate_fused_kernel, fmt=fmt,
                           target_max=target_max),
+        name=spans.TRUNCATE_FUSED,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda p, i, j: (i, j))],
         out_specs=[pl.BlockSpec((bm, bn), lambda p, i, j: (i, j)),

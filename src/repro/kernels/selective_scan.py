@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import auto_interpret
+from repro.obs import spans
 
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
@@ -70,6 +71,7 @@ def selective_scan_pallas(x, dt, bmat, cmat, a, d_skip, *,
     grid = (b, di // bd)
     y, hout = pl.pallas_call(
         functools.partial(_scan_kernel, seq_len=s),
+        name=spans.SELECTIVE_SCAN,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, s, bd), lambda i, j: (i, 0, j)),

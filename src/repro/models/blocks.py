@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.core import statsbank
 from repro.core.policy import Policy
+from repro.obs import spans
 from repro.parallel.sharding import shard
 
 _MASK = -1e30
@@ -243,7 +244,7 @@ def mlp_fwd(p, x, cfg: ArchConfig, pol: Policy):
     glu = cfg.activation.endswith("_glu")
     # named StatsBank scope: every GEMM truncation site inside this MLP
     # gets a stable ".../mlp/tN" key in the per-layer stats bank
-    with statsbank.scope("mlp"):
+    with statsbank.scope("mlp"), jax.named_scope(spans.MLP):
         hg = pol.dot(x, p["w_gate"].astype(x.dtype))
         hl = pol.dot(x, p["w_up"].astype(x.dtype)) if glu else None
         h = activate(hg, hl, cfg.activation)
@@ -403,6 +404,26 @@ def init_attn_block(cfg: ArchConfig, key, block_type: str) -> Dict[str, Any]:
 def attn_block_apply(p, x, cfg: ArchConfig, pol: Policy, positions,
                      cache, cache_index, mode: str, block_type: str,
                      cache_fmt: Optional[str] = None):
+    with jax.named_scope(spans.ATTN):
+        x, new_cache = _attention(p, x, cfg, pol, positions, cache,
+                                  cache_index, mode, block_type, cache_fmt)
+    aux = jnp.zeros((), jnp.float32)
+    xn2 = apply_norm(p["ln2"], x, cfg)
+    if block_type == "moe":
+        with statsbank.scope("moe"):
+            y, aux = moe_fwd(p["moe"], xn2, cfg, pol)
+    else:
+        y = mlp_fwd(p["mlp"], xn2, cfg, pol)
+    x = x + y
+    return shard(x, "batch", None, None), new_cache, aux
+
+
+def _attention(p, x, cfg: ArchConfig, pol: Policy, positions, cache,
+               cache_index, mode: str, block_type: str,
+               cache_fmt: Optional[str]):
+    """The attention half of ``attn_block_apply``: pre-norm, projections,
+    attention and the output projection with its residual.  Returns
+    (x, new_cache)."""
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     h, kvh = cfg.n_heads, cfg.kv_heads
@@ -525,17 +546,7 @@ def attn_block_apply(p, x, cfg: ArchConfig, pol: Policy, positions,
     attn = attn.reshape(b, kvh * (h // kvh), s, hd).transpose(0, 2, 1, 3).reshape(b, s, h * hd)
     with statsbank.scope("attn"):
         x = x + pol.dot(attn, p["wo"].astype(x.dtype))
-    x = shard(x, "batch", None, None)
-
-    aux = jnp.zeros((), jnp.float32)
-    xn2 = apply_norm(p["ln2"], x, cfg)
-    if block_type == "moe":
-        with statsbank.scope("moe"):
-            y, aux = moe_fwd(p["moe"], xn2, cfg, pol)
-    else:
-        y = mlp_fwd(p["mlp"], xn2, cfg, pol)
-    x = x + y
-    return shard(x, "batch", None, None), new_cache, aux
+    return shard(x, "batch", None, None), new_cache
 
 
 # =========================================================================

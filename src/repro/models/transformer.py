@@ -23,6 +23,7 @@ from repro.configs.base import ArchConfig
 from repro.core import statsbank
 from repro.core.policy import Policy
 from repro.models import blocks
+from repro.obs import spans
 from repro.parallel.sharding import shard
 
 
@@ -72,7 +73,7 @@ def embed_tokens(params, tokens, cfg: ArchConfig, pol: Policy):
 
 def lm_head(params, x, cfg: ArchConfig, pol: Policy):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    with statsbank.scope("head"):
+    with statsbank.scope("head"), jax.named_scope(spans.LM_HEAD):
         logits = pol.dot(x, w.astype(x.dtype))
     return shard(logits, "batch", None, "vocab")
 

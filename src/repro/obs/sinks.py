@@ -2,8 +2,11 @@
 
 A sink consumes flat dict records.  Every record carries a ``kind``:
 
-* ``"train_step"``  — TrainLoop per-step line: loss/lr plus span timings
-  (``data_ms``/``step_ms``/``ckpt_ms``/``refresh_ms``).
+* ``"train_step"``  — TrainLoop per-step line: loss/lr, the durations of
+  the step's spans (``data_ms``/``step_ms``/``ckpt_ms``) and whether it
+  refreshed the StatsBank (``refreshed``).
+* ``"span"`` / ``"counter"`` / ``"compile"`` — the aggregates of
+  :data:`repro.obs.spans.RECORDER`, emitted when a TrainLoop run ends.
 * ``"site_health"`` — one StatsBank site-direction's telemetry snapshot
   (keys per :data:`repro.obs.metrics.TELE_FIELDS` plus ``site``, ``dir``,
   ``staleness``, optional ``layer`` for scanned segments).
@@ -131,7 +134,8 @@ class ConsoleSink(MetricsSink):
         if kind == "train_step":
             self.print_fn(
                 f"step {r['step']:5d} loss {r['loss']:.4f} "
-                f"lr {r['lr']:.2e} t {r.get('step_ms', 0.0):.0f}ms")
+                f"lr {r['lr']:.2e} t {r.get('step_ms', 0.0):.0f}ms"
+                + (" refreshed" if r.get("refreshed") else ""))
         elif kind == "event" and r.get("event") == "watchdog":
             self.print_fn(
                 f"[watchdog] step {r['step']} took {r['dt_s']:.3f}s "

@@ -38,6 +38,7 @@ from repro.core import collectives
 from repro.core import s2fp8
 from repro.core import statsbank
 from repro.core.policy import Policy
+from repro.obs import spans
 from repro.obs import telemetry as obs_telemetry
 from repro.optim import optimizers as optim_mod
 from repro.optim.optimizers import Optimizer, global_norm
@@ -379,7 +380,7 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
             # sharded-leaf sum-of-squares over the fsdp axis.
             norm_scope = (optim_mod.fsdp_grads(fsdp_axis, elig)
                           if elig is not None else contextlib.nullcontext())
-            with norm_scope:
+            with norm_scope, jax.named_scope(spans.OPTIMIZER):
                 new_params, new_opt = optimizer.update(grads, opt_state,
                                                        params, lr)
                 # grads are post-sync (replicated-global under a mesh, or
@@ -556,11 +557,20 @@ class TrainLoop:
     stay log-only).
 
     ``sink``: a ``repro.obs.MetricsSink`` receiving the loop's records —
-    per-step ``"train_step"`` lines with span timings (data / device-
-    sync'd step / checkpoint / refresh wall-clock) and ``"event"``
-    records (watchdog trips, checkpoint saves, ladder interventions).
-    Defaults to a ``ConsoleSink`` over ``run``'s ``print_fn``, which
-    reproduces the historical log lines.
+    per-step ``"train_step"`` lines with the durations of the step's
+    ``train/data``, ``train/step`` (dispatch through ``block_until_ready``)
+    and ``train/ckpt`` spans and whether the step refreshed the StatsBank,
+    ``"event"`` records (watchdog trips, checkpoint saves, ladder
+    interventions), and at the end of ``run`` the span, counter and
+    compile records of ``repro.obs.spans.RECORDER``.  Defaults to a
+    ``ConsoleSink`` over ``run``'s ``print_fn``, which reproduces the
+    historical log lines.
+
+    Each step runs inside a ``jax.profiler.StepTraceAnnotation`` named
+    ``train``, so a profiler trace shows the loop's steps, and their
+    spans, on the device trace's clock.  The jitted step's function is
+    registered with the recorder, which then tells its trace, lowering
+    and compile apart from every other function's.
     """
 
     def __init__(self, train_step, params, opt_state, data_fn,
@@ -574,6 +584,8 @@ class TrainLoop:
         donate = tuple(range(2 + (stats_bank is not None)
                              + (guard_state is not None)))
         self.train_step = jax.jit(train_step, donate_argnums=donate)
+        spans.install_compile_listener()
+        spans.RECORDER.register_step(train_step)
         self.params = params
         self.opt_state = opt_state
         self.stats_bank = stats_bank
@@ -678,7 +690,6 @@ class TrainLoop:
         return step + 1
 
     def run(self, steps: int, print_fn=print):
-        import time
         from repro.obs.sinks import ConsoleSink
         sink = self.sink if self.sink is not None else ConsoleSink(print_fn)
         watchdog = fault.Watchdog(self.watchdog_factor)
@@ -687,103 +698,111 @@ class TrainLoop:
         interventions = 0
         step = self.start_step
         while step < steps:
-            t_fetch = time.perf_counter()
-            # the data may be made by a jitted program: wait for it here,
-            # so that its time counts as data_s and not as step time
-            batch = jax.block_until_ready(self.data_fn(step))
-            data_s = time.perf_counter() - t_fetch
-            if self.chaos is not None:
-                mutated = self.chaos.mutate_bank(step, self.stats_bank)
-                if mutated is not None:
-                    self.stats_bank = mutated
-            t0 = time.perf_counter()
-            if self.chaos is not None:
-                # straggler injection lands INSIDE the timed span so the
-                # watchdog sees it
-                self.chaos.maybe_sleep(step)
-            metrics = self._step_once(batch, step)
-            # device-sync the span: the step dispatches asynchronously, so
-            # wall-clock without the barrier measures dispatch, not compute
-            jax.block_until_ready((self.params, metrics))
-            dt = time.perf_counter() - t0
-            metrics = {k: (float(v) if hasattr(v, "item") and getattr(v, 'ndim', 1) == 0 else v)
-                       for k, v in metrics.items()}
-            metrics["step_s"], metrics["data_s"] = dt, data_s
-            # straggler watchdog: flag steps > factor x trailing median
-            event = watchdog.observe(step, dt)
-            if event is not None:
-                sink.emit({"kind": "event", "event": "watchdog",
-                           "step": step, **event})
-                wd_consecutive += 1
-                if self.watchdog_escalate_after and \
-                        wd_consecutive >= self.watchdog_escalate_after:
-                    if self.ring is not None:
-                        self.ring.push(step + 1, self._state_tree())
-                    sink.emit({"kind": "event", "event": "watchdog_escalated",
-                               "step": step, "trips": wd_consecutive,
-                               "snapshot": self.ring is not None})
+            with jax.profiler.StepTraceAnnotation(spans.STEP_TRACE,
+                                                  step_num=step):
+                with spans.span(spans.TRAIN_DATA) as data_span:
+                    # the data may be made by a jitted program: wait for
+                    # it here, so that its time counts as data_s and not
+                    # as step time
+                    batch = jax.block_until_ready(self.data_fn(step))
+                data_s = data_span.seconds
+                if self.chaos is not None:
+                    mutated = self.chaos.mutate_bank(step, self.stats_bank)
+                    if mutated is not None:
+                        self.stats_bank = mutated
+                with spans.span(spans.TRAIN_STEP) as step_span:
+                    if self.chaos is not None:
+                        # straggler injection lands INSIDE the timed span
+                        # so the watchdog sees it
+                        self.chaos.maybe_sleep(step)
+                    metrics = self._step_once(batch, step)
+                    # device-sync the span: the step dispatches
+                    # asynchronously, so wall-clock without the barrier
+                    # measures dispatch, not compute
+                    jax.block_until_ready((self.params, metrics))
+                dt = step_span.seconds
+                metrics = {k: (float(v) if hasattr(v, "item")
+                               and getattr(v, 'ndim', 1) == 0 else v)
+                           for k, v in metrics.items()}
+                metrics["step_s"], metrics["data_s"] = dt, data_s
+                # straggler watchdog: flag steps > factor x trailing median
+                event = watchdog.observe(step, dt)
+                if event is not None:
+                    sink.emit({"kind": "event", "event": "watchdog",
+                               "step": step, **event})
+                    wd_consecutive += 1
+                    if self.watchdog_escalate_after and \
+                            wd_consecutive >= self.watchdog_escalate_after:
+                        if self.ring is not None:
+                            self.ring.push(step + 1, self._state_tree())
+                        sink.emit({"kind": "event",
+                                   "event": "watchdog_escalated",
+                                   "step": step, "trips": wd_consecutive,
+                                   "snapshot": self.ring is not None})
+                        wd_consecutive = 0
+                else:
                     wd_consecutive = 0
-            else:
-                wd_consecutive = 0
-            self.history.append(metrics)
-            tripped = (self.guard_state is not None
-                       and metrics.get("guard_ok", 1.0) < 0.5)
-            if tripped:
-                trips += 1
-                interventions += 1
-                cause = ",".join(c for c in ("nonfinite", "spike", "sat",
-                                             "forced")
-                                 if metrics.get(f"guard_{c}", 0.0) >= 0.5)
-                sink.emit({"kind": "event", "event": "guard_tripped",
-                           "step": step, "trip": trips,
-                           "cause": cause or "unknown",
-                           "loss": metrics.get("loss"),
-                           "grad_norm": metrics.get("grad_norm")})
-                if interventions > self.max_interventions:
-                    sink.flush()
-                    raise RuntimeError(
-                        f"StepGuard: {interventions} interventions without "
-                        f"recovery (last trip at step {step}, cause "
-                        f"{cause or 'unknown'}) — giving up")
-                next_step = self._escalate(step, trips, sink)
-                if next_step <= step:
-                    trips = 0               # rewound: the ladder restarts
-                step = next_step
-                continue
-            trips = 0
-            if self.ring is not None and self.snapshot_every and \
-                    (step + 1) % self.snapshot_every == 0:
-                # the state ENTERING step+1 — last-good by construction
-                # (this step just passed the guard)
-                self.ring.push(step + 1, self._state_tree())
-            t1 = time.perf_counter()
-            saved = False
-            if self.ckpt is not None and self.ckpt_every and \
-                    (step + 1) % self.ckpt_every == 0:
-                self.ckpt.save(step + 1, self._ckpt_tree(), blocking=False)
-                saved = True
-            ckpt_s = time.perf_counter() - t1
-            if saved:
-                sink.emit({"kind": "event", "event": "checkpoint_saved",
-                           "step": step + 1, "blocking_s": ckpt_s,
-                           "write_s": getattr(self.ckpt,
-                                              "last_write_seconds", 0.0)})
-            if self.chaos is not None and self.ckpt is not None:
-                damage = self.chaos.corrupt_checkpoint(step, self.ckpt)
-                if damage is not None:
-                    sink.emit({"kind": "event",
-                               "event": "chaos_corrupt_ckpt",
-                               "step": step, **damage})
-            if self.log_every and step % self.log_every == 0:
-                refreshed = bool(metrics.get("stats_refreshed", 0.0))
-                sink.emit({"kind": "train_step", "step": step,
-                           "loss": metrics["loss"], "lr": metrics["lr"],
-                           "grad_norm": metrics.get("grad_norm"),
-                           "data_ms": data_s * 1e3, "step_ms": dt * 1e3,
-                           "ckpt_ms": ckpt_s * 1e3 if saved else 0.0,
-                           "refresh_ms": dt * 1e3 if refreshed else 0.0})
-            step += 1
+                self.history.append(metrics)
+                tripped = (self.guard_state is not None
+                           and metrics.get("guard_ok", 1.0) < 0.5)
+                if tripped:
+                    trips += 1
+                    interventions += 1
+                    cause = ",".join(c for c in ("nonfinite", "spike", "sat",
+                                                 "forced")
+                                     if metrics.get(f"guard_{c}", 0.0) >= 0.5)
+                    sink.emit({"kind": "event", "event": "guard_tripped",
+                               "step": step, "trip": trips,
+                               "cause": cause or "unknown",
+                               "loss": metrics.get("loss"),
+                               "grad_norm": metrics.get("grad_norm")})
+                    if interventions > self.max_interventions:
+                        sink.flush()
+                        raise RuntimeError(
+                            f"StepGuard: {interventions} interventions "
+                            f"without recovery (last trip at step {step}, "
+                            f"cause {cause or 'unknown'}) — giving up")
+                    next_step = self._escalate(step, trips, sink)
+                    if next_step <= step:
+                        trips = 0               # rewound: the ladder restarts
+                    step = next_step
+                    continue
+                trips = 0
+                if self.ring is not None and self.snapshot_every and \
+                        (step + 1) % self.snapshot_every == 0:
+                    # the state ENTERING step+1 — last-good by construction
+                    # (this step just passed the guard)
+                    self.ring.push(step + 1, self._state_tree())
+                saved = bool(self.ckpt is not None and self.ckpt_every
+                             and (step + 1) % self.ckpt_every == 0)
+                ckpt_s = 0.0
+                if saved:
+                    with spans.span(spans.TRAIN_CKPT) as ckpt_span:
+                        self.ckpt.save(step + 1, self._ckpt_tree(),
+                                       blocking=False)
+                    ckpt_s = ckpt_span.seconds
+                    sink.emit({"kind": "event", "event": "checkpoint_saved",
+                               "step": step + 1, "blocking_s": ckpt_s,
+                               "write_s": getattr(self.ckpt,
+                                                  "last_write_seconds", 0.0)})
+                if self.chaos is not None and self.ckpt is not None:
+                    damage = self.chaos.corrupt_checkpoint(step, self.ckpt)
+                    if damage is not None:
+                        sink.emit({"kind": "event",
+                                   "event": "chaos_corrupt_ckpt",
+                                   "step": step, **damage})
+                if self.log_every and step % self.log_every == 0:
+                    sink.emit({"kind": "train_step", "step": step,
+                               "loss": metrics["loss"], "lr": metrics["lr"],
+                               "grad_norm": metrics.get("grad_norm"),
+                               "data_ms": data_s * 1e3, "step_ms": dt * 1e3,
+                               "ckpt_ms": ckpt_s * 1e3,
+                               "refreshed": metrics.get("stats_refreshed",
+                                                        0.0) >= 0.5})
+                step += 1
         if self.ckpt is not None:
             self.ckpt.wait()
+        for record in spans.RECORDER.records():
+            sink.emit(record)
         sink.flush()
         return self.history

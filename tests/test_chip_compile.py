@@ -5,7 +5,9 @@ d_ff 6912, head_dim 256, 4 query heads over 1 KV head, window 512) for a
 ``v5e:2x2`` topology that is described, not attached, and asserts that the
 TPU compiler accepted it and emitted the Mosaic kernel (``tpu_custom_call``).
 This catches what interpret mode cannot: block shapes that break the
-(8, 128) tiling rule, scalar stores to VMEM, VMEM over-use.
+(8, 128) tiling rule, scalar stores to VMEM, VMEM over-use.  One test
+compiles a whole tiny S2FP8 train step and reads the names its kernels get
+in the compiled program, which are the names a TPU trace shows.
 
 The topology is described inside a module fixture, never at import, so that
 every pytest-xdist worker collects the same tests and only the worker that
@@ -13,6 +15,7 @@ runs this file loads the TPU compiler.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 
@@ -165,3 +168,63 @@ def test_paged_decode_compiles(spec):
         spec((n_blocks, KVH, block, HD), F8), s, s, s, s,
         spec((slots, max_blocks), jnp.int32), spec((slots,), jnp.int32))
     _assert_kernel(text)
+
+
+def test_train_step_kernels_carry_registry_names(spec, monkeypatch):
+    """A whole S2FP8 train step, its layers under ``jax.checkpoint`` and
+    its StatsBank refreshes under ``lax.cond``, compiled for the chip:
+    every Mosaic call's HLO instruction name is a name of
+    ``repro.obs.spans``, not that of what wraps the call (``checkpoint``,
+    ``branch_0_fun``, ``jvp_jit_...``)."""
+    from repro.configs import get_reduced_config
+    from repro.core import statsbank
+    from repro.core.policy import make_policy
+    from repro.kernels import s2fp8_matmul, s2fp8_quant
+    from repro.models import transformer as tlm
+    from repro.obs import spans
+    from repro.optim import optimizers, schedules
+    from repro.training.trainer import make_train_step
+
+    # default_backend() says CPU here: compile the kernels with the chip's
+    # blocks instead of interpreting them, and keep the traces made so out
+    # of the jit caches that later tests of this process use
+    for mod in (s2fp8_quant, s2fp8_matmul, fa):
+        monkeypatch.setattr(mod, "auto_interpret", lambda: False)
+    monkeypatch.setattr(dispatch, "pick_gemm_block",
+                        functools.partial(pick_gemm_block, platform="tpu"))
+    jax.clear_caches()
+    try:
+        cfg = get_reduced_config("minicpm_2b").replace(
+            n_layers=2, remat=True, vocab=256)
+        pol = make_policy("s2fp8", backend="pallas", gemm_mode="payload")
+        assert pol.uses_payload_gemm
+
+        def loss_fn(p, batch, pol_):
+            return tlm.loss_fn(p, batch["tokens"], batch["labels"], cfg,
+                               pol_)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda x: spec(x.shape, x.dtype), tree)
+
+        opt = optimizers.adamw()
+        stats = statsbank.StatsConfig(refresh_every=4)
+        params = jax.eval_shape(lambda k: tlm.init_lm(cfg, k),
+                                jax.random.PRNGKey(0))
+        batch = {k: spec((2, 128), jnp.int32) for k in ("tokens", "labels")}
+        bank = statsbank.init_bank(loss_fn, params, batch, pol, stats)
+        step = make_train_step(loss_fn, opt, schedules.constant(1e-3), pol,
+                               stats=stats)
+        text = _compile_text(step, on_chip(params),
+                             on_chip(jax.eval_shape(opt.init, params)),
+                             on_chip(bank), batch, spec((), jnp.int32))
+    finally:
+        jax.clear_caches()
+    names = collections.Counter(
+        line.strip().split(" = ", 1)[0].lstrip("%").rsplit(".", 1)[0]
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert set(names) <= set(spans.KERNELS), names
+    assert {spans.GEMM, spans.QUANT_APPLY, spans.TRUNCATE_APPLY,
+            spans.QFLASH_FWD, spans.QFLASH_BWD_DQ,
+            spans.QFLASH_BWD_DKDV} <= set(names), names

@@ -36,6 +36,7 @@ from repro.core.policy import make_policy
 from repro.obs import doctor as obs_doctor
 from repro.obs import metrics as obs_metrics
 from repro.obs import sinks as obs_sinks
+from repro.obs import spans
 from repro.optim import optimizers, schedules
 from repro.training import fault
 from repro.training.trainer import TrainLoop, make_train_step
@@ -243,6 +244,7 @@ def test_trainloop_emits_spans_and_checkpoint_events(tmp_path):
 
     sink = obs_sinks.MemorySink()
     ck = CheckpointManager(str(tmp_path))
+    spans.RECORDER.reset()
     loop = TrainLoop(train_step, {"w": jnp.zeros((4,))},
                      {"m": jnp.zeros((4,))},
                      lambda s: {"x": jnp.zeros((2,))},
@@ -251,13 +253,51 @@ def test_trainloop_emits_spans_and_checkpoint_events(tmp_path):
     steps = sink.by_kind("train_step")
     assert [r["step"] for r in steps] == [0, 1, 2, 3]
     for r in steps:
-        for k in ("loss", "lr", "data_ms", "step_ms", "ckpt_ms"):
+        for k in ("loss", "lr", "data_ms", "step_ms", "ckpt_ms",
+                  "refreshed"):
             assert k in r, (k, r)
-        assert r["step_ms"] >= 0.0
+        assert "refresh_ms" not in r
+        assert r["refreshed"] is False          # no StatsBank
+        assert r["step_ms"] > 0.0 and r["data_ms"] > 0.0
+    # the record's times are the durations of the step's spans
+    rec = spans.RECORDER.spans
+    for key, name in (("data_ms", spans.TRAIN_DATA),
+                      ("step_ms", spans.TRAIN_STEP),
+                      ("ckpt_ms", spans.TRAIN_CKPT)):
+        assert sum(r[key] for r in steps) == pytest.approx(
+            rec[name].total_ns * 1e-6)
+    assert rec[spans.TRAIN_STEP].count == 4
+    assert rec[spans.TRAIN_CKPT].count == 2     # after steps 1 and 3
+    assert [r["ckpt_ms"] > 0.0 for r in steps] == [False, True, False, True]
     saves = [r for r in sink.by_kind("event")
              if r["event"] == "checkpoint_saved"]
     assert [r["step"] for r in saves] == [2, 4]
     assert all("write_s" in r for r in saves)
+    assert all(r["blocking_s"] * 1e3 == pytest.approx(s["ckpt_ms"])
+               for r, s in zip(saves, steps[1::2]))
+    # the recorder's aggregates close the run
+    assert {r["name"] for r in sink.by_kind("span")} >= {
+        spans.TRAIN_DATA, spans.TRAIN_STEP, spans.TRAIN_CKPT}
+    assert {(r["fun_name"], r["phase"]) for r in sink.by_kind("compile")} >= {
+        ("train_step", spans.TRACE), ("train_step", spans.LOWER),
+        ("train_step", spans.COMPILE)}
+
+
+def test_train_launcher_console_marks_refresh_steps(capsys):
+    from repro.configs import get_reduced_config
+    from repro.launch import train as launch
+    args = launch.build_parser().parse_args(
+        ["--arch", "minicpm_2b", "--reduced", "--steps", "11", "--batch",
+         "2", "--seq", "8", "--policy", "s2fp8", "--backend", "ref",
+         "--stats-refresh-every", "4"])
+    cfg = get_reduced_config("minicpm_2b").replace(n_layers=1, vocab=64)
+    launch.train(cfg, args)
+    lines = [line for line in capsys.readouterr().out.splitlines()
+             if line.startswith("step ")]
+    # TrainLoop logs every 10th step: step 0 refreshes, step 10 does not
+    assert [line.split()[1] for line in lines] == ["0", "10"]
+    assert lines[0].endswith(" refreshed"), lines
+    assert not lines[1].endswith(" refreshed"), lines
 
 
 # ---------------------------------------------------------------------------
