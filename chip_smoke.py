@@ -71,6 +71,17 @@ def compile_clock():
     return rec.total(spans.COMPILE), rec.counters.get(spans.CACHE_HITS, 0)
 
 
+def gemm_plan_counters():
+    """The payload GEMMs' plan counters so far (``gemm/*`` of the
+    program's recorder): MACs, padded MACs, dequantized and operand
+    elements."""
+    from repro.obs import spans
+    c = spans.RECORDER.counters
+    return [c.get(name, 0) for name in (
+        spans.GEMM_MACS, spans.GEMM_PADDED_MACS, spans.GEMM_DEQUANT_ELEMS,
+        spans.GEMM_OPERAND_ELEMS)]
+
+
 def smoke_config():
     from repro.configs.base import get_config
     cfg = get_config(ARCH)
@@ -342,13 +353,19 @@ def train_one_chip(cfg, batch: int, seq: int, steps: int, seed: int
 
     args = train_args(batch, seq, steps, seed, ("--mesh", "none"))
     before = compile_clock()[0]
+    plan_before = gemm_plan_counters()
     t0 = time.perf_counter()
     loop = train(cfg, args)
     wall = time.perf_counter() - t0
     losses_of(loop, steps)
     secs, hits = compile_clock()
+    # the GEMM plan of the traced step (counted per trace, not per step)
+    macs, padded, dequant, operand = (
+        after - b for after, b in zip(gemm_plan_counters(), plan_before))
     log(f"train phase: {wall:.2f} s wall, compile {secs - before:.2f}"
-        f" s, persistent-cache hits {hits}")
+        f" s, persistent-cache hits {hits}; GEMM plan: padded MACs "
+        f"{padded / max(macs, 1):.4%}, each operand element dequantized "
+        f"{dequant / max(operand, 1):.2f} times")
 
     # the step the loop ran, compiled again for inspection (a cache read
     # when the persistent cache holds it)

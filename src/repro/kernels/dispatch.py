@@ -25,7 +25,7 @@ core/backend.py builds the user-facing backend objects on top of these.
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -196,10 +196,23 @@ def truncate_nd(x: jnp.ndarray, *, stats=None, fmt: str = "e5m2",
 # quantized GEMM
 # ---------------------------------------------------------------------------
 
-def _gemm_pad_plan(layout, a_payload, b_payload, bm, bk, bn, axis0: int):
-    """Shared alignment/heuristic/padding of the 2-D GEMM tile of each
-    operand (``axis0`` = index of the tile's first axis: 0 for plain
-    GEMMs, 1 for batched ones — the leading batch axis needs no padding).
+class GemmPlan(NamedTuple):
+    """The grid of one payload GEMM: its logical dims, its blocks and the
+    dims it pads them to (multiples of the blocks)."""
+    m: int
+    k: int
+    n: int
+    bm: int
+    bk: int
+    bn: int
+    mp: int
+    kp: int
+    np: int
+
+
+def gemm_plan(layout: str, a_shape, b_shape, bm=None, bk=None,
+              bn=None) -> GemmPlan:
+    """Alignment and tile plan of a 2-D GEMM of stored operand shapes.
 
     Per-layout tile alignment: a GEMM dim needs the 128-lane multiple
     only where it is the LANE (last) dim of a stored operand or of the
@@ -207,11 +220,11 @@ def _gemm_pad_plan(layout, a_payload, b_payload, bm, bk, bn, axis0: int):
     "tn" (lane of the stored [K, M] operand).  K: lane of A ("nn") or
     of both operands ("nt"), rows-only under "tn".  N: always the
     output's lane.  This keeps small-M inference GEMMs at 8-row padding
-    instead of inflating them 16x.  Returns
-    ``(a_pad, b_pad, bm_, bk_, bn_, m, n)``.
+    instead of inflating them 16x.  The blocks are the caller's, else the
+    tile plan (``pick_gemm_block``) of the aligned dims; each dim is then
+    padded to a multiple of its block.
     """
-    m, k, n = gemm_dims(layout, a_payload.shape[axis0:],
-                        b_payload.shape[axis0:])
+    m, k, n = gemm_dims(layout, a_shape, b_shape)
     ma = _ceil_to(m, LANE_ALIGN if layout == "tn" else SUBLANE_ALIGN)
     ka = _ceil_to(k, SUBLANE_ALIGN if layout == "tn" else LANE_ALIGN)
     na = _ceil_to(n, LANE_ALIGN)
@@ -219,14 +232,35 @@ def _gemm_pad_plan(layout, a_payload, b_payload, bm, bk, bn, axis0: int):
     bm_ = min(hm if bm is None else bm, ma)
     bk_ = min(hk if bk is None else bk, ka)
     bn_ = min(hn if bn is None else bn, na)
-    mp, kp, np_ = _ceil_to(ma, bm_), _ceil_to(ka, bk_), _ceil_to(na, bn_)
-    pads = {"nn": ((mp, kp), (kp, np_)),
-            "nt": ((mp, kp), (np_, kp)),
-            "tn": ((kp, mp), (kp, np_))}[layout]
-    (ar, ac), (br, bc) = pads
+    return GemmPlan(m, k, n, bm_, bk_, bn_, _ceil_to(ma, bm_),
+                    _ceil_to(ka, bk_), _ceil_to(na, bn_))
+
+
+def _gemm_pad_plan(layout, a_payload, b_payload, bm, bk, bn, axis0: int):
+    """:func:`gemm_plan` of the 2-D GEMM tile of each operand, and the
+    operands zero-padded to it (``axis0`` = index of the tile's first
+    axis: 0 for plain GEMMs, 1 for batched ones — the leading batch axis
+    needs no padding).  The plan's MACs, padded MACs and dequantized
+    elements go to ``spans.RECORDER``'s ``gemm/*`` counters, once per
+    trace.  Returns ``(a_pad, b_pad, plan)``.
+    """
+    p = gemm_plan(layout, a_payload.shape[axis0:], b_payload.shape[axis0:],
+                  bm, bk, bn)
+    ga, gb = (a_payload.shape[0], b_payload.shape[0]) if axis0 else (1, 1)
+    g = max(ga, gb)
+    macs = p.mp * p.kp * p.np
+    spans.counter(spans.GEMM_MACS, g * macs)
+    spans.counter(spans.GEMM_PADDED_MACS, g * (macs - p.m * p.k * p.n))
+    spans.counter(spans.GEMM_DEQUANT_ELEMS,
+                  g * (macs // p.bn + macs // p.bm))
+    spans.counter(spans.GEMM_OPERAND_ELEMS,
+                  ga * p.m * p.k + gb * p.k * p.n)
+    (ar, ac), (br, bc) = {"nn": ((p.mp, p.kp), (p.kp, p.np)),
+                          "nt": ((p.mp, p.kp), (p.np, p.kp)),
+                          "tn": ((p.kp, p.mp), (p.kp, p.np))}[layout]
     a_pad = _pad_axis(_pad_axis(a_payload, axis0, ar), axis0 + 1, ac)
     b_pad = _pad_axis(_pad_axis(b_payload, axis0, br), axis0 + 1, bc)
-    return a_pad, b_pad, bm_, bk_, bn_, m, n
+    return a_pad, b_pad, p
 
 
 def qmatmul_nd(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta, *,
@@ -239,21 +273,21 @@ def qmatmul_nd(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta, *,
     Ragged dims are zero-padded to the block grid (payload zeros dequantize
     to 0.0, contributing nothing to the accumulation; the Eq. 5 epilogue
     maps zero to zero) and the result is sliced back.  Block sizes default
-    to the (M, K, N, platform) heuristic table in
+    to the tile plan of the aligned (M, K, N) in
     ``s2fp8_matmul.pick_gemm_block`` (``REPRO_GEMM_BLOCK`` overrides).
     ``epilogue_stats=(alpha, beta)`` fuses the output-site truncation into
     the kernel's last K step.
     """
-    a_pad, b_pad, bm_, bk_, bn_, m, n = _gemm_pad_plan(
-        layout, a_payload, b_payload, bm, bk, bn, axis0=0)
+    a_pad, b_pad, p = _gemm_pad_plan(layout, a_payload, b_payload, bm, bk,
+                                     bn, axis0=0)
     oa, ob = (None, None) if epilogue_stats is None else epilogue_stats
     out = s2fp8_matmul_pallas(a_pad, jnp.asarray(a_alpha, jnp.float32),
                               jnp.asarray(a_beta, jnp.float32),
                               b_pad, jnp.asarray(b_alpha, jnp.float32),
                               jnp.asarray(b_beta, jnp.float32),
                               oa, ob, layout=layout, fmt=fmt,
-                              bm=bm_, bk=bk_, bn=bn_, interpret=interpret)
-    return out[:m, :n]
+                              bm=p.bm, bk=p.bk, bn=p.bn, interpret=interpret)
+    return out[:p.m, :p.n]
 
 
 def qmatmul_batched_nd(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
@@ -271,8 +305,8 @@ def qmatmul_batched_nd(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
     dividing the combined batch) and ``out_batch`` reduction semantics
     live in ``s2fp8_matmul_batched_pallas``.
     """
-    a_pad, b_pad, bm_, bk_, bn_, m, n = _gemm_pad_plan(
-        layout, a_payload, b_payload, bm, bk, bn, axis0=1)
+    a_pad, b_pad, p = _gemm_pad_plan(layout, a_payload, b_payload, bm, bk,
+                                     bn, axis0=1)
     oa, ob = (None, None) if epilogue_stats is None else epilogue_stats
     out = s2fp8_matmul_batched_pallas(
         a_pad, jnp.asarray(a_alpha, jnp.float32),
@@ -280,5 +314,5 @@ def qmatmul_batched_nd(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
         b_pad, jnp.asarray(b_alpha, jnp.float32),
         jnp.asarray(b_beta, jnp.float32),
         oa, ob, layout=layout, out_batch=out_batch, fmt=fmt,
-        bm=bm_, bk=bk_, bn=bn_, interpret=interpret)
-    return out[:, :m, :n]
+        bm=p.bm, bk=p.bk, bn=p.bn, interpret=interpret)
+    return out[:, :p.m, :p.n]

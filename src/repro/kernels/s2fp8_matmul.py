@@ -20,12 +20,17 @@ Three additions make the kernel the *training* GEMM (core/qdot.py):
     map, shared ``_truncate_body``), so Fig. 4's separate output-truncation
     pass disappears.  The clamp turns stale-bank-stats overflow into
     saturation, never inf.
-  * a (M, K, N, platform)-keyed block heuristic (``pick_gemm_block``) with a
-    ``REPRO_GEMM_BLOCK=bm,bk,bn`` env override, replacing the fixed
-    (256, 256, 256) tiles — see kernels/README.md for the sweep.
+  * a tile plan from the GEMM's shape (``pick_gemm_block``): on the TPU
+    the widest output tiles that divide the aligned dims within a VMEM
+    budget (``gemm_vmem_bytes``), so dequantization amortizes over wide
+    tiles and no MAC is padding; a ``REPRO_GEMM_BLOCK=bm,bk,bn`` env
+    override — see kernels/README.md for the chip numbers.
 
 Grid is (M/bm, N/bn, K/bk) with K innermost; the output tile lives in VMEM
-across the K loop (constant index_map) and acts as the accumulator.
+across the K loop (constant index_map) and acts as the accumulator.  Inside
+a grid step each operand tile is dequantized once into f32 VMEM scratch, and
+the output tile accumulates one (_DOT_M, _DOT_N) block at a time, in loops,
+so the kernel's code does not grow with its tiles.
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import auto_interpret
 from repro.kernels.ref import GEMM_CONTRACT, GEMM_LAYOUTS, gemm_dims
@@ -48,6 +54,20 @@ from repro.obs import spans
 # every product exact to f32, as the paper's FP32-accumulate GEMM (§5) and
 # the oracles assume.  Every dot of the payload kernels uses it.
 MXU_PRECISION = jax.lax.Precision.HIGHEST
+# Mosaic unrolls a kernel's body over its whole tile, and a TPU program
+# keeps its code in HBM: a (1024, 384, 1920) body dequantizing and
+# multiplying whole tiles compiled to 4.5 MB of code, against 0.29 MB for
+# (256, 512, 256).  So the body works through the tile in loops of fixed
+# size.  The elementwise maps (dequant, Eq. 5) take a chunk of at most
+# _CHUNK_ROWS x _CHUNK_LANES at a time, a few vector registers, so that
+# their chains of temporaries stay in registers; _CHUNK_UNROLL chunks a
+# loop iteration give the scheduler independent chains to interleave.
+# Lowering a kernel costs time in proportion to the vector registers one
+# iteration touches: four chunks an iteration ran 2% faster than two on a
+# v5e, and lowered a MiniCPM-2B train step in 6 s more
+_CHUNK_ROWS, _CHUNK_LANES, _CHUNK_UNROLL = 32, 512, 2
+# and the most rows and columns of the output tile that one dot computes
+_DOT_M, _DOT_N = 512, 512
 
 
 def _dequant(y, alpha, beta):
@@ -58,26 +78,91 @@ def _dequant(y, alpha, beta):
     return jnp.where(nz, jnp.sign(y) * jnp.exp2(xlog), 0.0)
 
 
+def _by_chunks(rows: int, cols: int, fn) -> None:
+    """``fn(rows_slice, cols_slice)`` over a (rows, cols) tile, a chunk at
+    a time, in a loop."""
+    cr = _CHUNK_ROWS if rows % _CHUNK_ROWS == 0 else rows
+    cc = _dot_block(cols, _CHUNK_LANES)
+    nc = cols // cc
+    n = rows // cr * nc
+    unroll = next(u for u in range(_CHUNK_UNROLL, 0, -1) if n % u == 0)
+
+    def body(i, carry):
+        for u in range(unroll):
+            t = i * unroll + u
+            fn(pl.ds(pl.multiple_of(t // nc * cr, cr), cr),
+               pl.ds(pl.multiple_of(t % nc * cc, cc), cc))
+        return carry
+
+    jax.lax.fori_loop(0, n // unroll, body, 0)
+
+
+def _dequant_tile(dst, src, lead, alpha, beta):
+    """Dequantize the payload tile ``src[lead]`` into f32 VMEM ``dst``, once
+    per grid step: every dot of the step reads it from there."""
+    def fill(rows, cols):
+        dst[rows, cols] = _dequant(src[lead + (rows, cols)], alpha, beta)
+    _by_chunks(*dst.shape, fill)
+
+
+def _dot_block(dim: int, cap: int) -> int:
+    """Rows or columns of the output tile one dot computes: the largest
+    multiple of 128 up to ``cap`` that divides ``dim``, else all of it."""
+    if dim <= cap:
+        return dim
+    return next((b for b in range(cap - cap % 128, 0, -128) if dim % b == 0),
+                dim)
+
+
+def _accumulate(o_ref, lead, a_deq, b_deq, layout):
+    """``o_ref[lead] += A B`` from the dequantized tiles, a (_DOT_M, _DOT_N)
+    block of the output at a time, each at ``MXU_PRECISION``."""
+    bm, bn = o_ref.shape[-2:]
+    tm, tn = _dot_block(bm, _DOT_M), _dot_block(bn, _DOT_N)
+    nj = bn // tn
+
+    def body(t, carry):
+        rows = pl.ds(pl.multiple_of(t // nj * tm, tm), tm)
+        cols = pl.ds(pl.multiple_of(t % nj * tn, tn), tn)
+        a = a_deq[:, rows] if layout == "tn" else a_deq[rows, :]
+        b = b_deq[cols, :] if layout == "nt" else b_deq[:, cols]
+        o_ref[lead + (rows, cols)] += jax.lax.dot_general(
+            a, b, GEMM_CONTRACT[layout], precision=MXU_PRECISION,
+            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, bm // tm * nj, body, 0)
+
+
+def _truncate_tile(o_ref, lead, alpha, beta, fmt):
+    """Eq. 5 on the finished accumulator tile ``o_ref[lead]``, in VMEM, a
+    chunk at a time: the output never crosses HBM untruncated, and the
+    map's f32 temporaries take one chunk's room, not the tile's.
+    Compiled for a v5e at (768, 512, 1920), tn, a GEMM that dequantized
+    and multiplied whole tiles asked for 21 MiB of VMEM without an
+    epilogue and 49 MiB with the whole tile mapped at once."""
+    def trunc(rows, cols):
+        idx = lead + (rows, cols)
+        o_ref[idx] = _truncate_body(o_ref[idx], alpha, beta, fmt)
+    _by_chunks(*o_ref.shape[-2:], trunc)
+
+
 def _matmul_kernel(aa_ref, ab_ref, ba_ref, bb_ref, oa_ref, ob_ref,
-                   a_ref, b_ref, o_ref, *, layout, epilogue, fmt):
+                   a_ref, b_ref, o_ref, a_deq, b_deq, *, layout, epilogue,
+                   fmt):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = _dequant(a_ref[...], aa_ref[0, 0], ab_ref[0, 0])
-    b = _dequant(b_ref[...], ba_ref[0, 0], bb_ref[0, 0])
-    o_ref[...] += jax.lax.dot_general(a, b, GEMM_CONTRACT[layout],
-                                      precision=MXU_PRECISION,
-                                      preferred_element_type=jnp.float32)
+    _dequant_tile(a_deq, a_ref, (), aa_ref[0, 0], ab_ref[0, 0])
+    _dequant_tile(b_deq, b_ref, (), ba_ref[0, 0], bb_ref[0, 0])
+    _accumulate(o_ref, (), a_deq, b_deq, layout)
     if epilogue:
         @pl.when(k == pl.num_programs(2) - 1)
         def _epilogue():
-            # Eq. 5 on the finished accumulator tile, in VMEM: the output
-            # never crosses HBM untruncated.
-            o_ref[...] = _truncate_body(o_ref[...], oa_ref[0, 0],
-                                        ob_ref[0, 0], fmt)
+            _truncate_tile(o_ref, (), oa_ref[0, 0], ob_ref[0, 0], fmt)
 
 
 def _operand_specs(layout, bm, bk, bn):
@@ -94,12 +179,20 @@ def _operand_specs(layout, bm, bk, bn):
     return a_spec, b_spec
 
 
+def _dequant_scratch(a_spec, b_spec):
+    """f32 VMEM for the dequantized operand tiles (a batched block's
+    leading 1 dropped)."""
+    return [pltpu.VMEM(spec.block_shape[-2:], jnp.float32)
+            for spec in (a_spec, b_spec)]
+
+
 # ---------------------------------------------------------------------------
 # batched variant: third (leading) data axis, broadcast/reduce via index maps
 # ---------------------------------------------------------------------------
 
 def _batched_matmul_kernel(aa_ref, ab_ref, ba_ref, bb_ref, oa_ref, ob_ref,
-                           a_ref, b_ref, o_ref, *, layout, epilogue, fmt):
+                           a_ref, b_ref, o_ref, a_deq, b_deq, *, layout,
+                           epilogue, fmt):
     gr = pl.program_id(3)
     k = pl.program_id(4)
 
@@ -107,18 +200,14 @@ def _batched_matmul_kernel(aa_ref, ab_ref, ba_ref, bb_ref, oa_ref, ob_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = _dequant(a_ref[...][0], aa_ref[0, 0], ab_ref[0, 0])
-    b = _dequant(b_ref[...][0], ba_ref[0, 0], bb_ref[0, 0])
-    o_ref[...] += jax.lax.dot_general(a, b, GEMM_CONTRACT[layout],
-                                      precision=MXU_PRECISION,
-                                      preferred_element_type=jnp.float32
-                                      )[None]
+    _dequant_tile(a_deq, a_ref, (0,), aa_ref[0, 0], ab_ref[0, 0])
+    _dequant_tile(b_deq, b_ref, (0,), ba_ref[0, 0], bb_ref[0, 0])
+    _accumulate(o_ref, (0,), a_deq, b_deq, layout)
     if epilogue:
         @pl.when(jnp.logical_and(gr == pl.num_programs(3) - 1,
                                  k == pl.num_programs(4) - 1))
         def _epilogue():
-            o_ref[...] = _truncate_body(o_ref[...], oa_ref[0, 0],
-                                        ob_ref[0, 0], fmt)
+            _truncate_tile(o_ref, (0,), oa_ref[0, 0], ob_ref[0, 0], fmt)
 
 
 def _batched_operand_specs(layout, bm, bk, bn, go, ga, gb):
@@ -147,35 +236,86 @@ def _batched_operand_specs(layout, bm, bk, bn, go, ga, gb):
 
 
 # ---------------------------------------------------------------------------
-# block-size heuristic
+# tile plan
 # ---------------------------------------------------------------------------
 
-# (platform, size-class) -> (bm, bk, bn).  Chosen by the sweep recorded in
-# kernels/README.md ("GEMM block heuristic"); VMEM budget per entry =
-# fp8 operand tiles (bm*bk + bk*bn bytes) + their f32 dequant images (x4)
-# + the f32 accumulator (bm*bn*4), double-buffered on the operand side.
-#   tpu/small : K often fits one step; modest tiles keep the grid >= core
-#               count for pipelining.
-#   tpu/large : widen K to 512 (1-byte payload tiles make deep-K cheap:
-#               512*256 fp8 = 128 KiB/operand tile) to cut accumulator
-#               revisits; ~3.5 MiB resident, safe with double buffering.
-#   interpret : grid iterations are Python-speed, so prefer the fewest,
-#               fattest tiles that divide the padded problem.
-_BLOCK_TABLE = {
-    ("tpu", "s"): (128, 256, 128),
-    ("tpu", "m"): (256, 256, 256),
-    ("tpu", "l"): (256, 512, 256),
-    ("interpret", "s"): (256, 256, 256),
-    ("interpret", "m"): (256, 512, 256),
-    ("interpret", "l"): (512, 512, 512),
-}
+# Every (i, j, k) grid step dequantizes its A and B tiles, so A is
+# dequantized N/bn times and B M/bm times: 1/bm + 1/bn elements of
+# per-element log2/exp2 work per MAC, spent again for every output tile, on
+# top of a fixed per-step overhead.  On the TPU the plan therefore takes the
+# widest output tiles that divide the (8/128-aligned) GEMM within a VMEM
+# budget, and a K tile that divides K, so no MAC is padding.
+_TPU_MAX_BK = 512
+_TPU_MAX_BM = 1024
+_TPU_MAX_BN = 2048
+# a dim with no 128-multiple divisor of at least this pads to a block
+_TPU_MIN_DIVISOR = 256
+_TPU_PAD_BLOCKS = (512, 384, 256)
+_MIB = 1 << 20
+# the most gemm_vmem_bytes a plan may take: on a v5e, tiles past it ran at
+# 82-87% of the tiles below it (kernels/README.md)
+_TPU_VMEM_BUDGET = 28 * _MIB
+# the compiler's default scoped-VMEM limit on a v5e, and the most a GEMM
+# may ask for (a v5e core has 128 MiB)
+_VMEM_FLOOR = 16 * _MIB
+_VMEM_CEIL = 48 * _MIB
+
+# interpret mode: grid iterations are Python-speed, so the fewest, fattest
+# tiles that divide the padded problem
+_INTERPRET_BLOCKS = {"s": (256, 256, 256), "m": (256, 512, 256),
+                     "l": (512, 512, 512)}
+
+
+def gemm_vmem_bytes(bm: int, bk: int, bn: int) -> int:
+    """VMEM one grid step of the payload GEMM holds, in bytes: the FP8
+    operand tiles double-buffered, 2 (bm bk + bk bn); their f32 dequant
+    images, 4 (bm bk + bk bn); the bf16 parts of the HIGHEST split, ~6
+    (bm bk + bk bn), a bound now that the dots take blocks of the tiles;
+    and the f32 output tile double-buffered, 8 bm bn."""
+    return 12 * (bm * bk + bk * bn) + 8 * bm * bn
+
+
+def gemm_vmem_limit(bm: int, bk: int, bn: int) -> int:
+    """``vmem_limit_bytes`` for a (bm, bk, bn) grid: the formula with
+    headroom for what it leaves out, never below the compiler's default
+    nor above ``_VMEM_CEIL``."""
+    need = gemm_vmem_bytes(bm, bk, bn)
+    return min(_VMEM_CEIL, max(_VMEM_FLOOR, need + need // 2))
+
+
+def _tpu_tiles(dim: int, cap: int) -> tuple:
+    """Blocks that may tile ``dim``: the whole ``dim`` if it is at most
+    ``cap``; else the multiples of 128 from ``_TPU_MIN_DIVISOR`` to
+    ``cap`` that divide it; else the one block of ``_TPU_PAD_BLOCKS`` that
+    pads ``dim`` least (the larger on a tie), so padding stays under 512."""
+    if dim <= cap:
+        return (dim,)
+    fits = tuple(b for b in range(_TPU_MIN_DIVISOR, cap + 1, 128)
+                 if dim % b == 0)
+    return fits or (min(_TPU_PAD_BLOCKS, key=lambda b: -(-dim // b) * b),)
+
+
+def _tpu_plan(m: int, k: int, n: int):
+    """The largest K block, then the output tiles with the least dequant
+    work per MAC, 1/bm + 1/bn (the larger tile on a tie), whose
+    :func:`gemm_vmem_bytes` fits ``_TPU_VMEM_BUDGET``."""
+    bk = max(_tpu_tiles(k, _TPU_MAX_BK))
+    pairs = [(bm, bn) for bm in _tpu_tiles(m, _TPU_MAX_BM)
+             for bn in _tpu_tiles(n, _TPU_MAX_BN)]
+    fit = [p for p in pairs
+           if gemm_vmem_bytes(p[0], bk, p[1]) <= _TPU_VMEM_BUDGET]
+    bm, bn = min(fit or pairs,
+                 key=lambda p: (1 / p[0] + 1 / p[1], -p[0] * p[1]))
+    return bm, bk, bn
 
 
 def pick_gemm_block(m: int, k: int, n: int, platform: str | None = None):
-    """(bm, bk, bn) for a logical (M, K, N) GEMM on ``platform``.
+    """(bm, bk, bn) for a GEMM of aligned dims (M, K, N) on ``platform``.
 
-    ``REPRO_GEMM_BLOCK=bm,bk,bn`` overrides the table globally (perf
-    triage / sweeps without a code edit)."""
+    TPU: :func:`_tpu_plan`, with bk up to 512, bm up to 1024 and bn up to
+    2048; a decode-size M keeps its whole 8-row tile.
+    ``REPRO_GEMM_BLOCK=bm,bk,bn`` overrides both platforms (perf triage /
+    sweeps without a code edit)."""
     env = os.environ.get("REPRO_GEMM_BLOCK")
     if env:
         try:
@@ -186,9 +326,11 @@ def pick_gemm_block(m: int, k: int, n: int, platform: str | None = None):
         return bm, bk, bn
     if platform is None:
         platform = "tpu" if jax.default_backend() == "tpu" else "interpret"
+    if platform == "tpu":
+        return _tpu_plan(m, k, n)
     size = max(m, k, n)
-    cls = "s" if size <= 512 else ("m" if size <= 2048 else "l")
-    return _BLOCK_TABLE[(platform, cls)]
+    return _INTERPRET_BLOCKS["s" if size <= 512 else
+                             ("m" if size <= 2048 else "l")]
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +368,10 @@ def s2fp8_matmul_pallas(a_payload, a_alpha, a_beta, b_payload, b_alpha, b_beta,
         grid=grid,
         in_specs=[scalar] * 6 + [a_spec, b_spec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        scratch_shapes=_dequant_scratch(a_spec, b_spec),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=gemm_vmem_limit(bm, bk, bn)),
         interpret=interpret,
     )(jnp.asarray(a_alpha, jnp.float32).reshape(1, 1),
       jnp.asarray(a_beta, jnp.float32).reshape(1, 1),
@@ -280,7 +425,10 @@ def s2fp8_matmul_batched_pallas(a_payload, a_alpha, a_beta,
         grid=grid,
         in_specs=[scalar] * 6 + [a_spec, b_spec],
         out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, gr, kk: (gi, i, j)),
+        scratch_shapes=_dequant_scratch(a_spec, b_spec),
         out_shape=jax.ShapeDtypeStruct((go, m, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=gemm_vmem_limit(bm, bk, bn)),
         interpret=interpret,
     )(jnp.asarray(a_alpha, jnp.float32).reshape(1, 1),
       jnp.asarray(a_beta, jnp.float32).reshape(1, 1),

@@ -72,6 +72,18 @@ QUANT_KERNELS = (STATS, QUANT_APPLY, DEQUANT, TRUNCATE_APPLY, TRUNCATE_FUSED)
 KERNELS = (GEMM, GEMM_BATCHED) + QUANT_KERNELS + (
     QFLASH_FWD, QFLASH_BWD_DQ, QFLASH_BWD_DKDV, FLASH, PAGED_DECODE,
     SELECTIVE_SCAN)
+# what the payload GEMMs' tile plan (``kernels/dispatch._gemm_pad_plan``)
+# makes of the GEMMs of a program: counted when a GEMM is planned, which is
+# once per trace of a jitted program, not once per executed step.
+# MACs the grids run, the part of them on zero padding, the operand elements
+# dequantized over the grids (each A tile once per output column tile, each
+# B tile once per output row tile), and the operands' own elements; so
+# padded / macs is the padded share, dequant_elems / operand_elems how many
+# times an operand element is dequantized
+GEMM_MACS = "gemm/macs"
+GEMM_PADDED_MACS = "gemm/padded_macs"
+GEMM_DEQUANT_ELEMS = "gemm/dequant_elems"
+GEMM_OPERAND_ELEMS = "gemm/operand_elems"
 
 # -- counters and compile phases ---------------------------------------------
 CACHE_HITS = "compile/cache_hits"

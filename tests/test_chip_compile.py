@@ -85,6 +85,40 @@ def test_qmatmul_compiles(spec, layout, a_shape, b_shape):
     _assert_kernel(text)
 
 
+# MiniCPM-2B's GEMMs at their real sizes (d_model 2304, d_ff 5760, the tied
+# 122753-wide LM head; 2 x 1024 tokens), under the TPU tile plan
+MINICPM_D, MINICPM_FF, MINICPM_VOCAB = 2304, 5760, 122753
+
+
+@pytest.mark.parametrize("layout,a_shape,b_shape", [
+    ("nn", (TOKENS, MINICPM_D), (MINICPM_D, MINICPM_FF)),      # x W_up
+    ("nt", (TOKENS, MINICPM_FF), (MINICPM_D, MINICPM_FF)),     # g W_up^T
+    ("tn", (TOKENS, MINICPM_D), (TOKENS, MINICPM_FF)),         # x^T g
+    ("nt", (TOKENS, MINICPM_D), (MINICPM_VOCAB, MINICPM_D)),   # x E^T
+])
+def test_qmatmul_compiles_at_minicpm_widths_with_planned_tiles(
+        spec, monkeypatch, layout, a_shape, b_shape):
+    """The plan's widest tiles, with the Eq. 5 epilogue, fit the VMEM limit
+    the kernel asks for: an overflow fails here, without a chip."""
+    from repro.kernels.s2fp8_matmul import gemm_vmem_limit
+    monkeypatch.delenv("REPRO_GEMM_BLOCK", raising=False)
+    monkeypatch.setattr(dispatch, "pick_gemm_block",
+                        functools.partial(pick_gemm_block, platform="tpu"))
+    p = dispatch.gemm_plan(layout, a_shape, b_shape)
+    assert (p.mp, p.kp, p.np) == (
+        dispatch._ceil_to(p.m, 128), dispatch._ceil_to(p.k, 128),
+        dispatch._ceil_to(p.n, 128))            # nothing padded to a block
+    s = spec(())
+    fn = functools.partial(dispatch.qmatmul_nd, layout=layout, bm=p.bm,
+                           bk=p.bk, bn=p.bn, interpret=False)
+    text = _compile_text(
+        lambda a, aa, ab, b, ba, bb, oa, ob: fn(
+            a, aa, ab, b, ba, bb, epilogue_stats=(oa, ob)),
+        spec(a_shape, F8), s, s, spec(b_shape, F8), s, s, s, s)
+    _assert_kernel(text)
+    assert gemm_vmem_limit(p.bm, p.bk, p.bn) > 16 * 2 ** 20
+
+
 @pytest.mark.parametrize("layout", ["nn", "nt", "tn"])
 def test_qmatmul_batched_compiles(spec, layout):
     from repro.kernels.ref import gemm_dims

@@ -12,6 +12,7 @@ Acceptance anchors (core/qdot.py, ISSUE 3):
   * steady-state banked steps run zero stats reductions outside lax.cond;
   * e4m3 storage parity rides the same path (``fmt``/``qdtype`` plumbing).
 """
+import functools
 import os
 
 import jax
@@ -27,8 +28,8 @@ from repro.core.backend import plan_einsum
 from repro.core.policy import make_policy
 from repro.core.s2fp8 import S2FP8Tensor
 from repro.kernels import dispatch
-from repro.kernels.ref import (GEMM_SPECS, dot_error_bound, gemm_dims,
-                               truncation_bracket)
+from repro.kernels.ref import (GEMM_CONTRACT, GEMM_SPECS, dot_error_bound,
+                               gemm_dims, truncation_bracket)
 from repro.kernels.s2fp8_matmul import pick_gemm_block
 
 jax.config.update("jax_platform_name", "cpu")
@@ -622,3 +623,170 @@ def test_block_heuristic_table_and_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_GEMM_BLOCK", "banana")
     with pytest.raises(ValueError):
         pick_gemm_block(256, 256, 256)
+
+
+# ---------------------------------------------------------------------------
+# the TPU tile plan at the benchmark cells' widths
+# ---------------------------------------------------------------------------
+
+TOKENS = 2048                       # batch 2 x seq 1024
+# (d_in, d_out) of every linear of MiniCPM-2B (d_model 2304, d_ff 5760, the
+# tied 122753-wide head) and StableLM-2-12B (d_model 5120, GQA k/v 1280,
+# d_ff 13824, one chip's 12544 rows of the vocabulary)
+CELL_LINEARS = [(2304, 2304), (2304, 5760), (5760, 2304), (2304, 122753),
+                (5120, 5120), (5120, 1280), (5120, 13824), (13824, 5120),
+                (5120, 12544)]
+
+
+def _training_gemm(layout, d_in, d_out, t=TOKENS):
+    """Stored operand shapes of a linear's forward (nn), input gradient
+    (nt: g W^T) or weight gradient (tn: x^T g) GEMM."""
+    return {"nn": ((t, d_in), (d_in, d_out)),
+            "nt": ((t, d_out), (d_in, d_out)),
+            "tn": ((t, d_in), (t, d_out))}[layout]
+
+
+def _plan_for_tpu(monkeypatch):
+    """Plan as on a TPU: this process's backend is the CPU."""
+    monkeypatch.delenv("REPRO_GEMM_BLOCK", raising=False)
+    monkeypatch.setattr(dispatch, "pick_gemm_block",
+                        functools.partial(pick_gemm_block, platform="tpu"))
+
+
+def _has_tile_divisor(dim, cap):
+    return dim <= cap or any(dim % b == 0 for b in range(256, cap + 1, 128))
+
+
+@pytest.mark.parametrize("layout", ["nn", "nt", "tn"])
+@pytest.mark.parametrize("d_in,d_out", CELL_LINEARS)
+def test_tpu_tile_plan_at_cell_widths(monkeypatch, layout, d_in, d_out):
+    """Every block divides its padded dim; a dim with a 128-multiple
+    divisor of at least 256 pads nothing beyond its 128/8 alignment, and
+    no dim pads by 512 or more (the benchmark maps a call back to its
+    published size within that); the VMEM formula fits the limit the
+    kernel passes, which stays within 48 MiB."""
+    from repro.kernels import s2fp8_matmul as sm
+    _plan_for_tpu(monkeypatch)
+    a_shape, b_shape = _training_gemm(layout, d_in, d_out)
+    p = dispatch.gemm_plan(layout, a_shape, b_shape)
+    aligned = (dispatch._ceil_to(p.m, 128 if layout == "tn" else 8),
+               dispatch._ceil_to(p.k, 8 if layout == "tn" else 128),
+               dispatch._ceil_to(p.n, 128))
+    caps = (sm._TPU_MAX_BM, sm._TPU_MAX_BK, sm._TPU_MAX_BN)
+    for block, padded, dim, cap in zip((p.bm, p.bk, p.bn),
+                                       (p.mp, p.kp, p.np), aligned, caps):
+        assert padded % block == 0 and block <= cap
+        assert padded - dim < 512
+        if _has_tile_divisor(dim, cap):
+            assert padded == dim, (block, dim)
+    need = sm.gemm_vmem_bytes(p.bm, p.bk, p.bn)
+    limit = sm.gemm_vmem_limit(p.bm, p.bk, p.bn)
+    assert need <= limit <= 48 * 2 ** 20
+
+
+@pytest.mark.parametrize("layout", ["nn", "nt", "tn"])
+def test_tpu_tile_plan_decode_and_ragged_dims(monkeypatch, layout):
+    """Decode-size M (8 rows) keeps an 8-row tile where M is a row dim; a
+    dim with no 128-multiple divisor of at least 256 (128 x 131) pads to
+    a block of at most 512, by less than 512; K of at most 512 is one
+    block."""
+    from repro.kernels import s2fp8_matmul as sm
+    _plan_for_tpu(monkeypatch)
+    a_shape, b_shape = _training_gemm(layout, 2304, 122753, t=8)
+    p = dispatch.gemm_plan(layout, a_shape, b_shape)
+    if layout == "tn":                  # M = 2304 is A's lane, K = 8 rows
+        assert (p.bk, p.kp) == (8, 8)
+    else:
+        assert (p.bm, p.mp) == (8, 8)
+    ragged = 128 * 131
+    a_shape, b_shape = _training_gemm(layout, ragged, ragged, t=ragged)
+    p = dispatch.gemm_plan(layout, a_shape, b_shape)
+    for block, padded in zip((p.bm, p.bk, p.bn), (p.mp, p.kp, p.np)):
+        assert 256 <= block <= 512 and padded % block == 0
+        assert 0 < padded - ragged < 512
+    assert sm.gemm_vmem_bytes(p.bm, p.bk, p.bn) <= sm.gemm_vmem_limit(
+        p.bm, p.bk, p.bn)
+    assert pick_gemm_block(256, 512, 384, platform="tpu") == (256, 512, 384)
+
+
+# ---------------------------------------------------------------------------
+# interpret parity at the plan's tile kinds: K tiles of 384, output tiles
+# that are not square
+# ---------------------------------------------------------------------------
+
+# (bm, bk, bn): two K steps of 384; the second's output tile takes two of
+# the kernel's (512, 512) dot blocks
+PLAN_TILES = [(128, 384, 256), (256, 384, 1024)]
+
+
+@pytest.mark.parametrize("tiles", PLAN_TILES)
+@pytest.mark.parametrize("epilogue", [False, True])
+@pytest.mark.parametrize("layout", ["nn", "nt", "tn"])
+def test_gemm_parity_at_plan_tiles(layout, epilogue, tiles):
+    m, k, n = 256, 768, 1024
+    ash, bsh = {"nn": ((m, k), (k, n)), "nt": ((m, k), (n, k)),
+                "tn": ((k, m), (k, n))}[layout]
+    a = jax.random.normal(jax.random.PRNGKey(40), ash) * 1e-2
+    b = jax.random.normal(jax.random.PRNGKey(41), bsh) * 1e-2
+    pal, ref = nbackend.get_backend("pallas"), nbackend.get_backend("ref")
+    qa, qb = pal.quantize(a), pal.quantize(b)
+    da, db = s2fp8.dequantize(qa), s2fp8.dequantize(qb)
+    exact = jax.lax.dot_general(da, db, GEMM_CONTRACT[layout],
+                                precision=jax.lax.Precision.HIGHEST)
+    bound = dot_error_bound(GEMM_SPECS[layout], da, db)
+    bm, bk, bn = tiles
+
+    def run(epilogue_stats=None):
+        return np.asarray(dispatch.qmatmul_nd(
+            qa.payload, qa.alpha, qa.beta, qb.payload, qb.alpha, qb.beta,
+            layout=layout, epilogue_stats=epilogue_stats, bm=bm, bk=bk,
+            bn=bn))
+
+    raw = run()
+    assert raw.shape == (m, n)
+    assert (np.abs(raw - np.asarray(exact)) <= bound).all()
+    if epilogue:
+        so = ref.compute_stats(raw)
+        lo, hi = truncation_bracket(
+            exact, bound, lambda y: ref.truncate(jnp.asarray(y), stats=so))
+        out = run(so)
+        assert ((lo <= out) & (out <= hi)).all()
+        # and it is the separate truncation of the raw GEMM, bit for bit
+        np.testing.assert_array_equal(
+            out, np.asarray(pal.truncate(jnp.asarray(raw), stats=so)))
+
+
+@pytest.mark.parametrize("tiles", PLAN_TILES)
+@pytest.mark.parametrize("epilogue", [False, True])
+def test_batched_gemm_parity_at_plan_tiles_with_broadcast(epilogue, tiles):
+    """The batched kernel with B broadcast over A's batch (a weight shared
+    by every slice), at the plan's tile kinds."""
+    g, m, k, n = 2, 256, 768, 1024
+    a = jax.random.normal(jax.random.PRNGKey(42), (g, m, k)) * 1e-2
+    b = jax.random.normal(jax.random.PRNGKey(43), (1, k, n)) * 1e-2
+    pal, ref = nbackend.get_backend("pallas"), nbackend.get_backend("ref")
+    qa, qb = pal.quantize(a), pal.quantize(b)
+    da, db = s2fp8.dequantize(qa), s2fp8.dequantize(qb)
+    exact = jnp.einsum("gmk,kn->gmn", da, db[0],
+                       precision=jax.lax.Precision.HIGHEST)
+    bound = dot_error_bound("gmk,kn->gmn", da, db[0])
+    bm, bk, bn = tiles
+
+    def run(epilogue_stats=None):
+        return np.asarray(dispatch.qmatmul_batched_nd(
+            qa.payload, qa.alpha, qa.beta, qb.payload, qb.alpha, qb.beta,
+            layout="nn", epilogue_stats=epilogue_stats, bm=bm, bk=bk,
+            bn=bn))
+
+    raw = run()
+    assert raw.shape == (g, m, n)
+    assert (np.abs(raw - np.asarray(exact)) <= bound).all()
+    if epilogue:
+        so = ref.compute_stats(raw)
+        lo, hi = truncation_bracket(
+            exact, bound, lambda y: ref.truncate(jnp.asarray(y), stats=so))
+        out = run(so)
+        assert ((lo <= out) & (out <= hi)).all()
+        # and it is the separate truncation of the raw GEMM, bit for bit
+        np.testing.assert_array_equal(
+            out, np.asarray(pal.truncate(jnp.asarray(raw), stats=so)))

@@ -156,3 +156,53 @@ def test_trainloop_steps_and_spans_show_in_a_profiler_trace(tmp_path):
             assert sa - MS <= a and b <= sb + MS     # inside its step
     assert spans.RECORDER.spans[spans.TRAIN_STEP].count == 3
     assert spans.RECORDER.step_names == {"train_step"}
+
+
+def test_gemm_plan_counters_count_each_traced_gemm():
+    """``dispatch`` counts a payload GEMM's grid when it plans it: MACs
+    over the padded grid, the padded part, the operand elements
+    dequantized over the grid and the operands' own elements."""
+    from repro.kernels import dispatch
+    f8 = jnp.float8_e5m2
+    a, b = jnp.zeros((100, 200), f8), jnp.zeros((200, 300), f8)
+    spans.RECORDER.reset()
+    jax.jit(lambda a, b: dispatch.qmatmul_nd(
+        a, 1.0, 0.0, b, 1.0, 0.0, bm=128, bk=128, bn=128)).lower(a, b)
+    # M 100 -> 104 rows, one 104-row block; K 200 -> 256; N 300 -> 384
+    macs = 104 * 256 * 384
+    gemm = {k: v for k, v in spans.RECORDER.counters.items()
+            if k.startswith("gemm/")}
+    assert gemm == {
+        spans.GEMM_MACS: macs,
+        spans.GEMM_PADDED_MACS: macs - 100 * 200 * 300,
+        spans.GEMM_DEQUANT_ELEMS: macs // 128 + macs // 104,
+        spans.GEMM_OPERAND_ELEMS: 100 * 200 + 200 * 300}
+    # batched, B broadcast over A's 3 slices: each slice's grid counts
+    spans.RECORDER.reset()
+    jax.jit(lambda a, b: dispatch.qmatmul_batched_nd(
+        a, 1.0, 0.0, b, 1.0, 0.0, bm=128, bk=128, bn=128)).lower(
+        jnp.zeros((3, 100, 200), f8), jnp.zeros((1, 200, 300), f8))
+    assert spans.RECORDER.counters[spans.GEMM_MACS] == 3 * macs
+    assert spans.RECORDER.counters[spans.GEMM_OPERAND_ELEMS] == \
+        3 * 100 * 200 + 200 * 300
+
+
+def test_gemm_plan_counters_after_a_traced_training_step():
+    """A payload-GEMM step's forward and both backward GEMMs are counted
+    once per trace of the step, not per executed step."""
+    from repro.core.policy import make_policy
+    pol = make_policy("s2fp8", backend="pallas", gemm_mode="payload")
+    x = jnp.ones((16, 256), jnp.float32)
+    w = jnp.ones((256, 128), jnp.float32)
+    step = jax.jit(jax.grad(lambda w, x: jnp.sum(pol.dot(x, w))))
+    spans.RECORDER.reset()
+    step(w, x).block_until_ready()
+    step(w, x).block_until_ready()
+    c = spans.RECORDER.counters
+    # forward x W, dx = g W^T, dW = x^T g: 16 x 256 x 128 MACs each
+    assert c[spans.GEMM_MACS] == 3 * 16 * 256 * 128
+    assert c[spans.GEMM_PADDED_MACS] == 0
+    assert c[spans.GEMM_OPERAND_ELEMS] == (
+        (16 * 256 + 256 * 128) + (16 * 128 + 256 * 128)
+        + (16 * 256 + 16 * 128))
+    assert c[spans.GEMM_DEQUANT_ELEMS] >= c[spans.GEMM_OPERAND_ELEMS]
