@@ -15,7 +15,9 @@ lists where this departs from the published model.
 The reference runs layer by layer (``lax.scan`` over the stacked layers,
 each layer recomputed in the backward pass) and the LM head over blocks of
 tokens, so that the weights, the gradients and AdamW's moments of a cell
-fit on one chip beside it.
+fit on one chip beside it.  Where they do not, ``train(..., devices=...)``
+shards them, and the batch, over those chips, and ``jit`` partitions the
+same program.
 
 ``init_params`` also makes the weights that the benchmark hands to the
 program: the same tree, from the same seed, in one jitted call.
@@ -34,6 +36,7 @@ from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NORM_EPS = 1e-6
 ZLOSS = 1e-4
@@ -58,6 +61,59 @@ def sizes(config: Dict) -> Dict:
         "head_dim", config["hidden_size"] // config["num_attention_heads"])
     out.update(config["block"])
     return out
+
+
+# ---------------------------------------------------------------------------
+# what the harness asks of a reference: the program this configuration needs,
+# the counts its readers use, its CPU test sizes and its checks of a file
+# ---------------------------------------------------------------------------
+
+# the program's ``ArchConfig`` fields that must equal the sizes that run
+PROGRAM_FIELDS = ("d_model", "n_heads", "kv_heads", "head_dim", "d_ff",
+                  "vocab", "n_layers", "tie_embeddings", "rope_theta",
+                  "activation", "norm", "remat")
+
+# the CPU test sizes: every width shrunk, the block as the cells run it
+TINY = dict(d_model=128, n_heads=4, kv_heads=2, head_dim=32, d_ff=256,
+            vocab=512, n_layers=2, rope_theta=10000.0,
+            activation="silu_glu", norm="rms", remat=True)
+
+
+def program_fields(s: Dict) -> Dict:
+    """The program's configuration fields (a dotted name for a field of a
+    nested group) and the values they must have for sizes ``s``: the
+    sizes themselves, and every layer a dense block."""
+    out = {k: s[k] for k in PROGRAM_FIELDS}
+    out["resolved_pattern"] = ("dense",) * s["n_layers"]
+    return out
+
+
+def count_module():
+    """The module whose functions count this architecture's operations
+    and bytes for the readers (``counts.py`` at the benchmark's root)."""
+    import counts
+    return counts
+
+
+def tiny_sizes(s: Dict) -> Dict:
+    """``TINY`` with the embedding tied or untied as in ``s``."""
+    return dict(TINY, tie_embeddings=s["tie_embeddings"])
+
+
+def check_config(config: Dict) -> None:
+    """Raises ``ValueError`` where a configuration file's published keys
+    do not give the sizes that run: each key of ``KEYS`` read as is, and
+    the head size the file's own ``head_dim`` or else hidden_size /
+    num_attention_heads."""
+    s = sizes(config)
+    wrong = {key: (config[key], s[name]) for key, name in KEYS.items()
+             if s[name] != config[key]}
+    head = config.get("head_dim",
+                      config["hidden_size"] / config["num_attention_heads"])
+    if s["head_dim"] != head:
+        wrong["head_dim"] = (head, s["head_dim"])
+    if wrong:
+        raise ValueError(f"{config['name']}: file and sizes differ: {wrong}")
 
 
 # ---------------------------------------------------------------------------
@@ -91,12 +147,34 @@ def _is_spec(x):
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def init_params(s: Dict, key):
-    """f32 weights from ``key``: normal(0, scale) per leaf, norm scales 1."""
+def layout(s: Dict, devices):
+    """Shardings over ``devices`` of the weights (AdamW's moments alike)
+    and of a batch: each leaf split along its last axis where that divides
+    by the number of chips, else whole on each; a batch split by rows.
+    ``(None, None)`` for one chip."""
+    if devices is None or len(devices) == 1:
+        return None, None
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.asarray(devices), ("chips",))
+    n = len(devices)
+
+    def leaf(spec):
+        shape = spec[0]
+        split = ("chips",) if shape[-1] % n == 0 else (None,)
+        return NamedSharding(mesh, PartitionSpec(*(None,) * (len(shape) - 1),
+                                                 *split))
+
+    return (jax.tree_util.tree_map(leaf, param_shapes(s), is_leaf=_is_spec),
+            NamedSharding(mesh, PartitionSpec("chips")))
+
+
+def init_params(s: Dict, key, shardings=None):
+    """f32 weights from ``key``: normal(0, scale) per leaf, norm scales 1;
+    made where ``shardings`` (a tree like the weights') puts them."""
     spec = param_shapes(s)
     leaves, treedef = jax.tree_util.tree_flatten(spec, is_leaf=_is_spec)
 
-    @jax.jit
+    @functools.partial(jax.jit, out_shardings=shardings)
     def make(key):
         out = []
         for i, (shape, scale) in enumerate(leaves):
@@ -221,8 +299,9 @@ def _norms(tree):
 
 
 @functools.lru_cache(maxsize=None)
-def _step_fn(sizes_items, opt_items, num):
+def _step_fn(sizes_items, opt_items, num, devices=None):
     s, o = dict(sizes_items), dict(opt_items)
+    keep, _ = layout(s, devices)
 
     def step(params, m, v, t, tokens, labels, stats):
         with jax.default_matmul_precision("highest"):
@@ -246,6 +325,9 @@ def _step_fn(sizes_items, opt_items, num):
             lambda p, a, b: p - (lr * (a / c1) / (jnp.sqrt(b / c2) + o["eps"])
                                  + lr * o["weight_decay"] * p),
             params, m, v)
+        if keep is not None:
+            params, m, v = (jax.lax.with_sharding_constraint(t, keep)
+                            for t in (params, m, v))
         return params, m, v, loss, gnorms, stats
 
     return jax.jit(step, donate_argnums=(0, 1, 2))
@@ -270,15 +352,19 @@ def change_norms(params, s: Dict, key):
 
 
 def train(s: Dict, opt: Dict, key, batches, num=None,
-          half_batch: bool = False):
+          half_batch: bool = False, devices=None):
     """AdamW steps from the weights of ``key`` over ``batches``.
 
     Returns the loss of each step, the per-leaf norm of the first
     gradient (before the clip) and the per-leaf norm of the weights'
     change after the last step.  ``num`` puts a lower precision at every
     site (``Int4``, ``S2FP8``); ``half_batch`` drops the second half of
-    each batch's rows (a fault, for calibrating the comparison)."""
-    params = init_params(s, key)
+    each batch's rows (a fault, for calibrating the comparison).
+    ``devices``: the chips over which the weights, the moments and each
+    batch are sharded (``layout``)."""
+    devices = tuple(devices) if devices is not None else None
+    shard, rows = layout(s, devices)
+    params = init_params(s, key, shard)
     m = jax.tree_util.tree_map(jnp.zeros_like, params)
     v = jax.tree_util.tree_map(jnp.zeros_like, params)
     stats = num.init_stats(s) if num is not None else None
@@ -287,8 +373,10 @@ def train(s: Dict, opt: Dict, key, batches, num=None,
         tok, lab = batch["tokens"], batch["labels"]
         if half_batch:
             tok, lab = tok[: tok.shape[0] // 2], lab[: lab.shape[0] // 2]
+        if rows is not None:
+            tok, lab = jax.device_put((tok, lab), rows)
         step = _step_fn(tuple(sorted(s.items())), tuple(sorted(opt.items())),
-                        num if num is None else num.at_step(i))
+                        num if num is None else num.at_step(i), devices)
         params, m, v, loss, gnorms, stats = step(
             params, m, v, jnp.float32(i + 1), tok, lab, stats)
         losses.append(float(loss))

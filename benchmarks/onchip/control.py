@@ -16,6 +16,9 @@ For each seed it prints, as one JSON line each, the three numbers of
   step below 8 bits).
 * ``half_batch``: the reference put in the program's place with the
   second half of every batch's rows left out.
+* ``unsynced`` (cells on several chips): the program through the whole
+  harness with the exchange of gradients between its chips left out
+  (``faults.unsynced``).
 
 With ``--emulate`` it prints instead, for S2FP8 cells, the same numbers
 for the reference with S2FP8 itself at the program's sites (paper Eq.
@@ -39,6 +42,7 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 import compare  # noqa: E402
+import faults  # noqa: E402
 import generator  # noqa: E402
 import run as harness  # noqa: E402
 
@@ -46,7 +50,9 @@ import run as harness  # noqa: E402
 PROGRAM_CONTROL = {"bf16": "fp8"}
 
 
-def reference_readings(cell, seed: int, variant: str) -> dict:
+def reference_readings(cell, seed: int, variants, devices) -> dict:
+    """``{variant: numbers}``: the reference of each variant against the
+    plain f32 reference, which runs once for them all."""
     ref, s, job = cell.reference, cell.sizes, cell.job
     key = generator.seed_key(seed, stream=0)
     make = generator.make_batch_fn(seed, s["vocab"], job["batch"],
@@ -54,49 +60,58 @@ def reference_readings(cell, seed: int, variant: str) -> dict:
     batches = [make(i) for i in range(job["check_steps"])]
 
     def go(**kw):
-        out = ref.train(s, job["optimizer"], key, batches, **kw)
+        out = ref.train(s, job["optimizer"], key, batches, devices=devices,
+                        **kw)
         out["grad_norms"] = compare.flatten(out["grad_norms"])
         out["change_norms"] = compare.flatten(out["change_norms"])
         return out
 
+    kwargs = {
+        "control": dict(num=ref.Int4()),
+        "half_batch": dict(half_batch=True),
+        "s2fp8.fresh": dict(num=ref.S2FP8(refresh_every=1)),
+        "s2fp8.delayed": dict(num=ref.S2FP8(
+            refresh_every=job["stats_refresh_every"])),
+    }
     base = go()
-    if variant == "control":
-        other = go(num=ref.Int4())
-    elif variant == "half_batch":
-        other = go(half_batch=True)
-    elif variant == "s2fp8.fresh":
-        other = go(num=ref.S2FP8(refresh_every=1))
-    elif variant == "s2fp8.delayed":
-        other = go(num=ref.S2FP8(refresh_every=job["stats_refresh_every"]))
-    else:
-        raise ValueError(variant)
-    read = compare.readings(other, base)
-    out = {k: v for k, (v, _) in read.items()}
-    if variant.startswith("s2fp8"):
-        out.update({f"{k}.where": w for k, (_, w) in read.items()},
-                   losses=other["losses"], f32_losses=base["losses"],
-                   change_norms=other["change_norms"],
-                   f32_change_norms=base["change_norms"])
-    return out
+    rows = {}
+    for variant in variants:
+        other = go(**kwargs[variant])
+        read = compare.readings(other, base)
+        out = {k: v for k, (v, _) in read.items()}
+        if variant.startswith("s2fp8"):
+            out.update({f"{k}.where": w for k, (_, w) in read.items()},
+                       losses=other["losses"], f32_losses=base["losses"],
+                       change_norms=other["change_norms"],
+                       f32_change_norms=base["change_norms"])
+        rows[variant] = out
+    return rows
 
 
-def program_control(cell, seed: int, devices) -> dict:
-    lower = copy.copy(cell)
-    lower.job = dict(cell.job, policy=PROGRAM_CONTROL[cell.job["policy"]])
-    out = harness.run_cell(lower, seed, 1.0, False, devices)
+def program_readings(cell, seed: int, devices, wrap_step=None) -> dict:
+    out = harness.run_cell(cell, seed, 1.0, False, devices,
+                           wrap_step=wrap_step)
     return {k: v["value"] for k, v in out["checks"].items()}
 
 
 def readings(cell, seed: int, devices, emulate: bool = False) -> list:
     if emulate:
-        return [(v, reference_readings(cell, seed, v))
-                for v in ("s2fp8.fresh", "s2fp8.delayed")]
+        return list(reference_readings(
+            cell, seed, ("s2fp8.fresh", "s2fp8.delayed"), devices).items())
     rows = []
     if cell.job["policy"] in PROGRAM_CONTROL:
-        rows.append(("control", program_control(cell, seed, devices)))
+        lower = copy.copy(cell)
+        lower.job = dict(cell.job,
+                         policy=PROGRAM_CONTROL[cell.job["policy"]])
+        rows.append(("control", program_readings(lower, seed, devices)))
+        rows += reference_readings(cell, seed, ("half_batch",),
+                                   devices).items()
     else:
-        rows.append(("control", reference_readings(cell, seed, "control")))
-    rows.append(("half_batch", reference_readings(cell, seed, "half_batch")))
+        rows += reference_readings(cell, seed, ("control", "half_batch"),
+                                   devices).items()
+    if len(devices) > 1:
+        rows.append(("unsynced", program_readings(cell, seed, devices,
+                                                  faults.unsynced)))
     return rows
 
 
@@ -110,13 +125,12 @@ def main(argv=None) -> None:
     cell = harness.Cell(harness.load_json(harness.ROOT / "BENCHMARK.json"),
                         args.workload)
     import jax
-    devices = (jax.devices()[:1] if args.cpu
+    devices = (jax.devices()[:cell.chips] if args.cpu
                else harness.find_devices(cell.chips))
     harness.enable_compile_cache()
     sys.path.insert(0, str(harness.ROOT / "src"))
     for seed in (int(s) for s in args.seeds.split(",")):
-        for variant, read in readings(cell, seed, devices,
-                                      args.emulate):
+        for variant, read in readings(cell, seed, devices, args.emulate):
             print(json.dumps({"workload": cell.name, "seed": seed,
                               "variant": variant, **read}), flush=True)
 

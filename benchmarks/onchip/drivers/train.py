@@ -3,7 +3,12 @@
 Set-up builds one object, the program's ``TrainLoop`` around the step that
 ``repro.training.trainer.make_train_step`` returns (the factory the
 training launcher uses), with the weights made by the reference's
-``init_params`` from the seed and the batches by ``generator.py``.  Every
+``init_params`` from the seed and the batches by ``generator.py``.  On more
+than one chip the job sets the layout as the launcher's options do: the
+step is built on a ("data", "model") mesh of n x 1 over the cell's chips,
+with the job's ``shard_params`` and ``grad_sync`` (default ``replicated``
+and ``f32``), and the weights, the optimizer's state and every batch are
+made where the step takes them; ``batch`` is the global batch.  Every
 step, checked or timed, goes through one call, ``dispatch``: the next batch
 and the loop's jitted ``train_step`` on the state the last step returned,
 not waited for.  The first ``check_steps`` steps are each waited for; they
@@ -25,6 +30,7 @@ and ``setup_s`` (process start to the first timed step).
 from __future__ import annotations
 
 import collections
+import dataclasses
 import gc
 import math
 import shutil
@@ -37,12 +43,10 @@ if str(HERE) not in sys.path:
     sys.path.insert(0, str(HERE))
 
 import compare            # noqa: E402
-import counts             # noqa: E402
 import generator          # noqa: E402
 import peaks as peaks_mod  # noqa: E402
 import trace_reduce       # noqa: E402
 
-# the sizes that run (``Cell.sizes``): fields of the program's ArchConfig
 # seconds of steps the window keeps dispatched ahead of the one it waits
 # for, and at most this many steps: the TPU runtime holds 8 steps in
 # flight and makes a ninth dispatch wait, which would leave the host's
@@ -50,22 +54,39 @@ import trace_reduce       # noqa: E402
 AHEAD_S = 5.0
 AHEAD_MAX = 8
 
-ARCH_FIELDS = ("d_model", "n_heads", "kv_heads", "head_dim", "d_ff", "vocab",
-               "n_layers", "tie_embeddings", "rope_theta", "activation",
-               "norm", "remat")
+
+def _field(obj, name: str):
+    for part in name.split("."):
+        obj = getattr(obj, part)
+    return obj
+
+
+def _replace(obj, changes: dict):
+    """The dataclass ``obj`` with ``changes``, where a dotted name sets a
+    field of a nested group."""
+    top, nested = {}, collections.defaultdict(dict)
+    for name, value in changes.items():
+        head, _, rest = name.partition(".")
+        if rest:
+            nested[head][rest] = value
+        else:
+            top[head] = value
+    for head, sub in nested.items():
+        top[head] = _replace(getattr(obj, head), sub)
+    return dataclasses.replace(obj, **top)
 
 
 def program_config(cell):
     """The program's registered architecture, cut as the cell's
-    configuration says.  A registered size that differs from the
-    configuration's, other than the cuts, is an error."""
+    configuration says.  The configuration's reference names the fields
+    it needs and their values (``program_fields``); one that differs,
+    other than the cuts, is an error."""
     from repro.configs.base import get_config
-    name, sizes = cell.config["arch"], cell.sizes
-    arch = get_config(name).replace(**{k: sizes[k] for k in cell.cut})
-    wrong = {k: (getattr(arch, k), sizes[k]) for k in ARCH_FIELDS
-             if getattr(arch, k) != sizes[k]}
-    if arch.resolved_pattern != ("dense",) * sizes["n_layers"]:
-        wrong["pattern"] = arch.resolved_pattern
+    name = cell.config["arch"]
+    want = cell.reference.program_fields(cell.sizes)
+    arch = _replace(get_config(name), {k: want[k] for k in cell.cut})
+    wrong = {k: (_field(arch, k), v) for k, v in want.items()
+             if _field(arch, k) != v}
     if wrong:
         raise ValueError(f"{name}: the program's configuration differs "
                          f"from the file's: {wrong}")
@@ -82,6 +103,38 @@ def _check_tree(params, arch) -> None:
     if got != want:
         raise ValueError("the reference's weight tree does not match the "
                          "program's parameter tree")
+
+
+def _layout(job, devices):
+    """The mesh and ``make_train_step``'s layout arguments for the cell's
+    chips, as the training launcher passes them; no mesh on one chip."""
+    import jax
+    from jax.sharding import AxisType
+    if len(devices) == 1:
+        return None, {}
+    mesh = jax.make_mesh((len(devices), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+    return mesh, {"mesh": mesh,
+                  "grad_sync_mode": job.get("grad_sync", "f32"),
+                  "param_sharding": job.get("shard_params", "replicated")}
+
+
+def _shardings(mesh, layout, batch, params, opt_state, with_stats):
+    """Where the step on ``mesh`` takes its weights, optimizer state,
+    StatsBank (None without one) and batch, from their shapes
+    (``parallel.sharding.train_step_specs``)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.parallel import sharding as shd
+    specs = list(shd.train_step_specs(
+        batch, mesh, with_stats=with_stats,
+        param_sharding=layout["param_sharding"], params=params,
+        opt_state=opt_state)[0][:-1])
+    if not with_stats:
+        specs.insert(2, None)
+    return [None if spec is None else jax.tree_util.tree_map(
+        lambda p: NamedSharding(mesh, p), spec,
+        is_leaf=lambda p: isinstance(p, PartitionSpec)) for spec in specs]
 
 
 def _held_bytes(device) -> int:
@@ -117,6 +170,7 @@ def run(cell, *, seed, seconds, trace, devices, clock, process_age,
     o = job["optimizer"]
     batch, seq, n_check = job["batch"], job["seq"], job["check_steps"]
     arch = program_config(cell)
+    mesh, layout = _layout(job, devices)
 
     # -- the program's step, built as the training launcher builds it -----
     pol = make_policy(job["policy"], loss_scale=job["loss_scale"],
@@ -128,19 +182,30 @@ def run(cell, *, seed, seconds, trace, devices, clock, process_age,
     sched = schedules.make_schedule("constant", o["lr"], total_steps=1)
     every = job["stats_refresh_every"]
     stats_cfg = statsbank.StatsConfig(refresh_every=every) if every else None
-    step_fn = make_train_step(loss_fn, opt, sched, pol, stats=stats_cfg)
+    step_fn = make_train_step(loss_fn, opt, sched, pol, stats=stats_cfg,
+                              **layout)
     if wrap_step is not None:
         step_fn = wrap_step(step_fn)
     log(f"policy {pol.mode}: engine {pol.backend_obj.name}, payload GEMMs "
         f"{pol.uses_payload_gemm}; {arch.n_layers} layers, vocab "
         f"{arch.vocab}, {arch.n_params() / 1e6:.1f} M params; batch "
-        f"{batch} x {seq}")
+        f"{batch} x {seq}; mesh {mesh and dict(mesh.shape)}, params "
+        f"{layout.get('param_sharding')}, grad sync "
+        f"{layout.get('grad_sync_mode')}")
 
     key = generator.seed_key(seed, stream=0)
-    params = ref.init_params(sizes, key)
+    # where the step takes its weights, optimizer state, bank and batch
+    where = [None] * 4
+    if mesh is not None:
+        shapes = jax.eval_shape(lambda k: ref.init_params(sizes, k), key)
+        rows = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        where = _shardings(mesh, layout, {"tokens": rows, "labels": rows},
+                           shapes, jax.eval_shape(opt.init, shapes),
+                           stats_cfg is not None)
+    params = ref.init_params(sizes, key, where[0])
     _check_tree(params, arch)
     make_batch = generator.make_batch_fn(seed, sizes["vocab"], batch, seq,
-                                         job["tokens"])
+                                         job["tokens"], where[3])
 
     def data_fn(step):
         with jax.profiler.TraceAnnotation("bench/data"):
@@ -148,9 +213,18 @@ def run(cell, *, seed, seconds, trace, devices, clock, process_age,
 
     bank = (statsbank.init_bank(loss_fn, params, data_fn(0), pol, stats_cfg)
             if stats_cfg is not None else None)
-    loop = TrainLoop(step_fn, params, opt.init(params), data_fn,
+    if mesh is None:
+        opt_state = opt.init(params)
+    else:
+        # made where the step returns them, so that every step runs one
+        # compiled program (made eagerly, AdamW's moments would first be
+        # whole on one chip)
+        opt_state = jax.jit(opt.init, out_shardings=where[1])(params)
+        if bank is not None:
+            bank = jax.device_put(bank, where[2])
+    loop = TrainLoop(step_fn, params, opt_state, data_fn,
                      stats_bank=bank, sink=NullSink(), log_every=0)
-    del params, bank
+    del params, opt_state, bank
     # the step's state, in the order the loop's jitted step takes it; the
     # step donates it, so the loop's own references go stale after step 0
     state = [loop.params, loop.opt_state]
@@ -258,7 +332,8 @@ def run(cell, *, seed, seconds, trace, devices, clock, process_age,
         out["layer_ctx"] = {
             "trace": red, "sizes": sizes, "job": job, "chips": len(devices),
             "peaks": peaks_mod.peaks_for(devices[0].device_kind),
-            "steps": n, "history": window, "counts": counts, "log": log}
+            "steps": n, "history": window,
+            "counts": ref.count_module(), "log": log}
 
     # -- free the program's state, then the reference ---------------------
     for leaf in jax.tree_util.tree_leaves(state):
@@ -267,7 +342,7 @@ def run(cell, *, seed, seconds, trace, devices, clock, process_age,
     gc.collect()
     t_ref = time.perf_counter()
     ref_out = ref.train(sizes, o, key, [make_batch(i) for i in
-                                        range(n_check)])
+                                        range(n_check)], devices=devices)
     ref_out["grad_norms"] = compare.flatten(ref_out["grad_norms"])
     ref_out["change_norms"] = compare.flatten(ref_out["change_norms"])
     log(f"reference: {time.perf_counter() - t_ref:.3f} s, losses "

@@ -13,6 +13,8 @@ Rows are ``seq + 1`` long: the step reads ``tokens = row[:-1]`` and
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -30,9 +32,11 @@ def seed_key(seed: int, stream: int = 0):
     return jax.random.fold_in(key, (seed >> 32) & _WORD)
 
 
-def make_batch_fn(seed: int, vocab: int, batch: int, seq: int, spec: dict):
+def make_batch_fn(seed: int, vocab: int, batch: int, seq: int, spec: dict,
+                  sharding=None):
     """``fn(step) -> {"tokens", "labels"}``, [batch, seq] int32 each, made
-    by one jitted program from the seed and the step number."""
+    by one jitted program from the seed and the step number, and laid out
+    as ``sharding`` says (the same ids however they are laid out)."""
     if spec["dist"] != "zipf":
         raise ValueError(f"unknown token distribution {spec['dist']!r}")
     exponent = float(spec["exponent"])
@@ -40,7 +44,7 @@ def make_batch_fn(seed: int, vocab: int, batch: int, seq: int, spec: dict):
 
     # the key is an argument, not a constant of the program, so that one
     # compiled program (and one persistent-cache entry) serves every seed
-    @jax.jit
+    @functools.partial(jax.jit, out_shardings=sharding)
     def fn(key, step):
         perm_key, step_key = jax.random.split(key)
         step_key = jax.random.fold_in(step_key, step)

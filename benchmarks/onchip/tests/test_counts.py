@@ -132,7 +132,7 @@ def test_against_xla_cost_analysis_of_an_f32_step():
     the model count, give or take the elementwise work and the attention
     (XLA does the whole S x S, the count its causal half)."""
     import dense_decoder as ref
-    s = dict(tiny.TINY, d_model=256, d_ff=1024, head_dim=64, vocab=2048,
+    s = dict(ref.TINY, d_model=256, d_ff=1024, head_dim=64, vocab=2048,
              tie_embeddings=False, remat=False)
     job = {"batch": 4, "seq": 16}
     params = ref.init_params(s, jax.random.PRNGKey(0))

@@ -3,10 +3,12 @@ names, makes the same traffic from the same seed, and judges numbers
 against their limits."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import subprocess
 import sys
+import types
 
 import jax.numpy as jnp
 import numpy as np
@@ -44,30 +46,79 @@ def test_every_cell_finds_its_files(cell):
     for m in c.per_layer:
         assert callable(tiny.harness.reader(m["name"]).read)
     # what runs is the file's published keys, cut where ``reduced`` says
-    cfg, sizes = c.config, c.sizes
-    assert sizes["d_model"] == cfg["hidden_size"]
-    assert sizes["d_ff"] == cfg["intermediate_size"]
-    assert sizes["n_heads"] == cfg["num_attention_heads"]
-    assert sizes["kv_heads"] == cfg["num_key_value_heads"]
-    assert sizes["n_layers"] == cfg["num_hidden_layers"]
-    assert sizes["vocab"] == cfg["vocab_size"]
-    assert sizes["head_dim"] * sizes["n_heads"] == cfg["hidden_size"]
+    cfg = c.config
+    c.reference.check_config(cfg)
     entry = {x["name"]: x for x in BENCH["configs"]}[c.entry["config"]]
     assert sorted(entry["reduced"]) == sorted(cfg["reduced"])
     for key, cut in cfg["reduced"].items():
         assert cfg[key] == cut["run"] != cut["published"]
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
-def test_program_config_matches_the_file(cell):
+def test_check_config_takes_the_files_head_size():
+    ref = tiny.harness.load_module(tiny.ONCHIP / "configs"
+                                   / "dense_decoder.py")
+    cfg = json.loads((tiny.ONCHIP / "configs" / "minicpm-2b.json")
+                     .read_text())
+    # a head size that the file gives, wider than hidden / heads
+    ref.check_config(dict(cfg, head_dim=128))
+    assert ref.sizes(dict(cfg, head_dim=128))["head_dim"] == 128
+    # no head size given, and hidden / heads is no whole number
+    with pytest.raises(ValueError):
+        ref.check_config(dict(cfg, num_attention_heads=35))
+
+
+def _driver():
     sys.path.insert(0, str(tiny.ONCHIP / "drivers"))
     import train
+    return train
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_program_config_matches_the_file(cell):
+    train = _driver()
     c = tiny.harness.Cell(BENCH, cell)
     arch = train.program_config(c)
     assert arch.n_layers == c.sizes["n_layers"]
     c.sizes = dict(c.sizes, d_ff=1)
     with pytest.raises(ValueError):
         train.program_config(c)
+
+
+def _moe_stub_cell(monkeypatch):
+    """A cell of ``stub_moe.py``'s configuration, whose program is the
+    registered reduced DeepSeekMoE."""
+    from repro.configs import base, deepseek_moe_16b
+    stub = tiny.harness.load_module(tiny.ONCHIP / "tests" / "stub_moe.py")
+    want = deepseek_moe_16b.reduced()
+    monkeypatch.setattr(base, "get_config", lambda name: want)
+    return want, types.SimpleNamespace(
+        config=stub.CONFIG, reference=stub, sizes=stub.sizes(stub.CONFIG),
+        cut=[])
+
+
+@pytest.mark.parametrize("size", ["moe.top_k", "moe.dense_d_ff",
+                                  "moe.expert_d_ff", "d_model"])
+def test_program_config_of_another_block_kind(monkeypatch, size):
+    """A reference of another block kind (``stub_moe.py``) maps onto the
+    program's registered reduced DeepSeekMoE with no edit to the harness,
+    and a size the program does not have is refused."""
+    train = _driver()
+    want, c = _moe_stub_cell(monkeypatch)
+    assert train.program_config(c) == want
+    c.sizes = dict(c.sizes, **{size: c.sizes[size] + 1})
+    with pytest.raises(ValueError):
+        train.program_config(c)
+
+
+def test_program_config_cuts_a_nested_size(monkeypatch):
+    train = _driver()
+    want, c = _moe_stub_cell(monkeypatch)
+    c.sizes = dict(c.sizes, **{"moe.n_experts": 4})
+    c.cut = ["moe.n_experts"]
+    arch = train.program_config(c)
+    assert arch.moe.n_experts == 4
+    assert arch == want.replace(moe=dataclasses.replace(want.moe,
+                                                        n_experts=4))
 
 
 def test_generator_is_seeded():

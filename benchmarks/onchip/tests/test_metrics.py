@@ -25,12 +25,12 @@ def reader(name):
     return tiny.harness.reader(name)
 
 
-def ctx(ops, steps=4, history=()):
+def ctx(ops, steps=4, history=(), chips=1):
     red = T.Reduction(window_s=2.0, busy_s=1.5,
                       op_seconds={k: v for k, v in ops.items()},
                       op_counts={k: 1 for k in ops}, idle_gaps=[],
-                      n_devices=1)
-    return {"trace": red, "sizes": SIZES, "job": JOB, "chips": 1,
+                      n_devices=chips)
+    return {"trace": red, "sizes": SIZES, "job": JOB, "chips": chips,
             "peaks": PEAK, "steps": steps, "history": list(history),
             "counts": counts, "log": lambda msg: None}
 
@@ -107,3 +107,27 @@ def test_refresh_excess():
         ctx({}, history=hist)) == pytest.approx(20.0)
     assert reader("refresh_excess_pct").read(
         ctx({}, history=[{"step_s": 1.0}])) is None
+
+
+# collectives as a TPU trace names them (self time over the chips)
+COLLECTIVE_OPS = {
+    "%all-gather-start.3 = (f32[4,8]{1,0}, f32[16,8]{1,0}) "
+    "all-gather-start(f32[4,8]{1,0} %p.1), channel_id=1, dimensions={0}": 0.2,
+    "%all-gather-done.3 = f32[16,8]{1,0} all-gather-done((f32[4,8]{1,0}, "
+    "f32[16,8]{1,0}) %all-gather-start.3)": 0.1,
+    "%reduce-scatter.7 = bf16[4,8]{1,0} reduce-scatter(bf16[16,8]{1,0} "
+    "%convert.2), channel_id=2, dimensions={0}, to_apply=%add": 0.3,
+    "%all-reduce-fusion.1 = f32[8]{0} fusion(f32[8]{0} %x), kind=kOutput": 0.4,
+}
+
+
+def test_collective_exposed():
+    # 1.0 s of collectives' self time over 4 chips x a 2 s window; a
+    # fusion that reads a collective's output is no collective
+    ops = dict(COLLECTIVE_OPS, **{
+        "%fusion.2 = f32[16,8]{1,0} fusion(f32[16,8]{1,0} "
+        "%all-gather-done.3), kind=kLoop": 5.0, QUANT_OP: 1.0})
+    got = reader("collective_exposed_pct.train").read(ctx(ops, chips=4))
+    assert got == pytest.approx(100 * 1.0 / (4 * 2.0))
+    assert reader("collective_exposed_pct.train").read(
+        ctx({QUANT_OP: 1.0}, chips=4)) is None
